@@ -42,6 +42,7 @@ from benchmarks import common
 from repro.analytics import AnalyticsConfig, BlockAggregator, \
     WindowedAggregator
 from repro.core.engine import GeoEngine
+from repro.compile_cache import enable_compile_cache
 
 OUT_PATH = common.BENCH_GEO_PATH
 
@@ -133,6 +134,7 @@ def bench_window(bids, n_blocks, batch: int, repeats: int = 3):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="verify-sized run")

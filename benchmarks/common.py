@@ -2,6 +2,7 @@
 and the BENCH_geo.json run-trajectory appender."""
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import pickle
@@ -35,30 +36,46 @@ def append_bench_run(run: dict, out_path: str = BENCH_GEO_PATH) -> int:
 SCALE = dict(seed=0, n_states=16, counties_per_state=8, blocks_per_county=24)
 
 
-def get_census():
-    from repro.core.synth import build_synth_census
+# Source files whose code decides what the cached pickles hold.
+_BUILDERS = ("synth.py", "geometry.py", "cells.py")
+
+
+def _cache_path(stem: str, **params) -> str:
+    """Cache file keyed by the build parameters and a hash of the source
+    that builds it, so a stale pickle (other parameters, older code) is
+    never picked up: it simply has another name."""
+    core = os.path.join(os.path.dirname(__file__), "..", "src", "repro",
+                        "core")
+    h = hashlib.sha256(json.dumps(params, sort_keys=True).encode())
+    for name in _BUILDERS:
+        with open(os.path.join(core, name), "rb") as f:
+            h.update(f.read())
+    return os.path.join(CACHE, f"{stem}_{h.hexdigest()[:16]}.pkl")
+
+
+def _cached(path: str, build):
     os.makedirs(CACHE, exist_ok=True)
-    path = os.path.join(CACHE, "census.pkl")
     if os.path.exists(path):
         with open(path, "rb") as f:
             return pickle.load(f)
-    sc = build_synth_census(**SCALE)
+    obj = build()
     with open(path, "wb") as f:
-        pickle.dump(sc, f)
-    return sc
+        pickle.dump(obj, f)
+    return obj
+
+
+def get_census():
+    from repro.core.synth import build_synth_census
+    return _cached(_cache_path("census", **SCALE),
+                   lambda: build_synth_census(**SCALE))
 
 
 def get_covering(max_level: int = 9):
     from repro.core.cells import build_cell_covering
-    os.makedirs(CACHE, exist_ok=True)
-    path = os.path.join(CACHE, f"covering_L{max_level}.pkl")
-    if os.path.exists(path):
-        with open(path, "rb") as f:
-            return pickle.load(f)
-    cov = build_cell_covering(get_census().census, max_level=max_level)
-    with open(path, "wb") as f:
-        pickle.dump(cov, f)
-    return cov
+    return _cached(
+        _cache_path("covering", max_level=max_level, **SCALE),
+        lambda: build_cell_covering(get_census().census,
+                                    max_level=max_level))
 
 
 def sample_points(n: int, seed: int = 7):

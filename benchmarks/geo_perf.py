@@ -21,10 +21,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks import common
-from benchmarks.roofline import geo_roofline
+from benchmarks.roofline import DEVICE_PEAKS, geo_roofline
 from repro.core.artifact import GeoIndexSet
 from repro.core.engine import EngineConfig, GeoEngine
 from repro.core.fast import FastIndex, leaf_codes, locate_cells
+from repro.core.strategies import onepass_runs
+from repro.compile_cache import enable_compile_cache
 
 N_POINTS = int(os.environ.get("BENCH_GEO_N", 1_000_000))
 SMOKE_N = int(os.environ.get("BENCH_GEO_SMOKE_N", 20_000))
@@ -127,7 +129,8 @@ def autotune_onepass(census, cov, pts, bid, smoke, repeats=3):
     record ``core/plan.py`` reads so ``strategy="auto"`` picks the
     measured winner instead of hard-coded thresholds.
 
-    Returns (tuned GeoIndexSet, roofline row for the tuned kernel)."""
+    Returns (tuned GeoIndexSet, roofline row for the tuned kernel, None
+    where the device has no published peaks)."""
     n = pts.shape[0]
     iset = GeoIndexSet(census=census, covering=cov)
     sweep = BE_SWEEP_SMOKE if smoke else BE_SWEEP
@@ -148,23 +151,28 @@ def autotune_onepass(census, cov, pts, bid, smoke, repeats=3):
     dt_fx = t(jax.jit(lambda p, e=eng_fx: e.assign(p)), pts, r=repeats)
     rate_fx = n / dt_fx
     winner = "fast_onepass" if rate >= rate_fx else "fast_exact"
-    eng_best = GeoEngine.from_index_set(iset, "fast_onepass")
-    roof = geo_roofline("fast_onepass",
-                        lambda p, e=eng_best: e.assign(p).block, (pts,),
-                        n, repeats=repeats)
+    device_kind = jax.devices()[0].device_kind
+    roof = None
+    if device_kind in DEVICE_PEAKS:
+        eng_best = GeoEngine.from_index_set(iset, "fast_onepass")
+        roof = geo_roofline("fast_onepass",
+                            lambda p, e=eng_best: e.assign(p).block,
+                            (pts,), n, repeats=repeats)
+    frac = None if roof is None else float(roof["roofline_fraction"])
     iset.record_tuning({
         "winner": winner, "be": int(be),
-        "device_kind": jax.default_backend(),
+        "device_kind": device_kind,
         "pts_per_sec": float(rate),
         "baseline_pts_per_sec": float(rate_fx),
-        "roofline_fraction": float(roof["roofline_fraction"]),
+        "roofline_fraction": frac,
         "recorded": time.strftime("%Y-%m-%dT%H:%M:%S"),
     })
     iset.save(TUNED_INDEX_PATH)
     print(f"autotune winner: {winner} (onepass be={be}: "
           f"{rate/1e6:.2f}M pts/s vs fast_exact_fused "
           f"{rate_fx/1e6:.2f}M pts/s; roofline "
-          f"{roof['roofline_fraction']:.3f}) -> {TUNED_INDEX_PATH}")
+          f"{'not measured' if frac is None else f'{frac:.3f}'}) "
+          f"-> {TUNED_INDEX_PATH}")
     return iset, roof
 
 
@@ -190,6 +198,7 @@ def bench_fast_stages(census, cov, pts, bid):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="verify-sized run: small batch, no stage sweep")
@@ -203,9 +212,14 @@ def main():
     print(f"n={n_points} points, {len(cov.lo)} cells"
           + (" [smoke]" if args.smoke else ""))
 
-    tuned_iset, roof = autotune_onepass(census, cov, pts, bid,
-                                        smoke=args.smoke,
-                                        repeats=3 if args.smoke else 5)
+    tuned_iset = roof = None
+    if not onepass_runs():
+        print("one-pass autotune skipped: its kernel does not compile "
+              "for the TPU")
+    else:
+        tuned_iset, roof = autotune_onepass(census, cov, pts, bid,
+                                            smoke=args.smoke,
+                                            repeats=3 if args.smoke else 5)
     results = bench_strategies(census, cov, pts, bid,
                                repeats=3 if args.smoke else 5,
                                tuned_iset=tuned_iset, roof=roof)
@@ -215,7 +229,9 @@ def main():
     run = {"ts": time.strftime("%Y-%m-%dT%H:%M:%S"),
            "n_points": n_points, "n_cells": int(len(cov.lo)),
            "smoke": bool(args.smoke),
-           "backend": jax.default_backend(), "strategies": results}
+           "backend": jax.default_backend(),
+           "device_kind": jax.devices()[0].device_kind,
+           "strategies": results}
     n_runs = common.append_bench_run(run, OUT_PATH)
     print(f"wrote {os.path.normpath(OUT_PATH)} ({n_runs} runs)")
 

@@ -45,6 +45,7 @@ from benchmarks import common
 from repro.core.engine import EngineConfig, GeoEngine
 from repro.serving import (AsyncGeoServer, FrontendConfig, QueueFull,
                            ServeConfig)
+from repro.compile_cache import enable_compile_cache
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "results",
                         "BENCH_geo.json")
@@ -203,6 +204,7 @@ def find_qps_at_slo(server: AsyncGeoServer, requests, slo_ms: float,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="verify-sized: short trials, few search iters")

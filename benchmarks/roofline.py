@@ -22,7 +22,8 @@ measure the geo kernels live — achieved vs peak bandwidth/FLOPs per
 kernel from XLA cost analysis over the bench census (DESIGN.md §13).
 Each run appends a ``kind: "roofline_geo"`` row to
 ``results/BENCH_geo.json`` so the bandwidth trajectory accumulates next
-to the points/sec history:
+to the points/sec history.  It needs a chip listed in ``DEVICE_PEAKS``
+and fails anywhere else:
 
     PYTHONPATH=src python -m benchmarks.roofline --geo --smoke
 """
@@ -34,21 +35,26 @@ PEAK_FLOPS = 197e12       # bf16 / chip
 HBM_BW = 819e9            # B/s / chip
 ICI_BW = 50e9             # B/s / link
 
-# Nominal CPU anchors for the geo rows when the bench runs off-TPU.
-# Order-of-magnitude single-socket figures: the point of the geo rows is
-# the *trajectory* of achieved bandwidth on a fixed device kind (and the
-# memory- vs compute-bound verdict), not cross-device comparisons.
-CPU_PEAK_FLOPS = 1.0e12   # FLOP/s, vectorized f32
-CPU_MEM_BW = 80e9         # B/s
+# Published per-chip peaks (FLOP/s bf16, HBM B/s), keyed by
+# ``jax.devices()[0].device_kind``.  Source: Google Cloud documentation,
+# "TPU v5e".  A device that is not listed has no peaks: an error, never
+# a default.
+DEVICE_PEAKS = {
+    "TPU v5 lite": (PEAK_FLOPS, HBM_BW),
+}
 
 sys.path.insert(0, "src")
 
 
 def device_peaks(device_kind: str) -> tuple:
-    """(peak FLOP/s, peak B/s) for a jax backend kind."""
-    if device_kind == "tpu":
-        return PEAK_FLOPS, HBM_BW
-    return CPU_PEAK_FLOPS, CPU_MEM_BW
+    """(peak FLOP/s, peak B/s) of one chip of ``device_kind``."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(DEVICE_PEAKS)}); roofline shares need a "
+            f"chip listed in benchmarks/roofline.py") from None
 
 
 def active_params(arch: str, total: int) -> int:
@@ -140,11 +146,11 @@ def geo_roofline(name: str, fn, args_: tuple, n_points: int,
 
     from benchmarks import common
 
+    device_kind = jax.devices()[0].device_kind
+    peak_flops, peak_bw = device_peaks(device_kind)
     f = jax.jit(fn)
     cost = compiled_cost(f.lower(*args_).compile())
     dt, _ = common.timeit(f, *args_, repeats=repeats)
-    device_kind = jax.default_backend()
-    peak_flops, peak_bw = device_peaks(device_kind)
     achieved_bw = cost["bytes_accessed"] / dt
     achieved_flops = cost["flops"] / dt
     bw_frac = achieved_bw / peak_bw
@@ -174,6 +180,7 @@ def geo_main(smoke: bool) -> None:
 
     from benchmarks import common
     from repro.core.engine import EngineConfig, GeoEngine
+    from repro.core.strategies import onepass_runs
 
     n = int(min(1 << 18, 20_000 if smoke else 1 << 18))
     census = common.get_census().census
@@ -182,10 +189,13 @@ def geo_main(smoke: bool) -> None:
     pts = jnp.asarray(xy, jnp.float32)
     specs = {
         "fast_exact": ("fast", EngineConfig(mode="exact", fused=True)),
-        "fast_onepass": ("fast_onepass", EngineConfig()),
     }
+    if onepass_runs():
+        specs["fast_onepass"] = ("fast_onepass", EngineConfig())
     kernels = {}
-    print(f"geo roofline: n={n} points, device={jax.default_backend()}"
+    device_kind = jax.devices()[0].device_kind
+    device_peaks(device_kind)          # no chip in the table: fail first
+    print(f"geo roofline: n={n} points, device={device_kind}"
           + (" [smoke]" if smoke else ""))
     for name, (strategy, cfg) in specs.items():
         eng = GeoEngine.build(census, strategy, cfg, covering=cov)
@@ -199,13 +209,15 @@ def geo_main(smoke: bool) -> None:
               f"{row['bytes_per_point']:6.0f} B/pt | {row['dominant']}")
     run = {"ts": time.strftime("%Y-%m-%dT%H:%M:%S"),
            "kind": "roofline_geo", "smoke": bool(smoke),
-           "n_points": n, "device_kind": jax.default_backend(),
+           "n_points": n, "device_kind": device_kind,
            "kernels": kernels}
     n_runs = common.append_bench_run(run)
     print(f"wrote {common.BENCH_GEO_PATH} ({n_runs} runs)")
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("path", nargs="?", default="results/dryrun.json")
     ap.add_argument("--mesh", default=None,

@@ -4,7 +4,7 @@
 
 Prints ``name,us_per_call,derived`` CSV rows.  Sections:
   fig4   simple approach: single-device rate vs number of points
-  fig5   simple approach: device scaling (structural proxy; see note)
+  fig5   simple approach: scaling over the devices this process holds
   fig6   fast approach: single-device rate vs number of points (F-variants)
   fig7   fast approach: device scaling + sharded index
   table1 index memory for exact/approx/fanout/sharded variants
@@ -13,11 +13,10 @@ Prints ``name,us_per_call,derived`` CSV rows.  Sections:
   roofline  (separate: python -m benchmarks.roofline results/dryrun.json)
 """
 import argparse
-import subprocess
-import sys
 
 from benchmarks import common
 from benchmarks.common import emit, sample_points, timeit
+from repro.compile_cache import enable_compile_cache
 
 
 # ------------------------------------------------------------------ fig4
@@ -38,64 +37,69 @@ def fig4(quick=False):
 
 
 # ------------------------------------------------------------------ fig5
-def _scaling_subprocess(n_dev: int, mode: str, n_pts: int) -> float:
-    """Run a sharded assign in a fresh process with n_dev fake devices."""
-    code = f"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n_dev}"
-import sys, time, pickle
-import jax, jax.numpy as jnp, numpy as np
-sys.path.insert(0, "src"); sys.path.insert(0, ".")
-from benchmarks import common
-from repro.launch.mesh import make_test_mesh, use_mesh
-sc = common.get_census()
-xy, *_ = sc.sample_points(np.random.default_rng(7), {n_pts})
-pts = jnp.asarray(xy)
-if "{mode}" == "simple":
-    from repro.core.simple import SimpleConfig, SimpleIndex, assign_simple
-    idx = SimpleIndex.from_census(sc.census)
-    cfg = SimpleConfig(cap_state=0.5, cap_county=0.5, cap_block=0.5)
-    mesh = make_test_mesh(({n_dev}, 1))
+def _device_counts(quick: bool) -> list:
+    """Powers of two up to the devices this process holds (the paper's
+    processing-unit sweep).  For simulated CPU devices, launch with
+    XLA_FLAGS=--xla_force_host_platform_device_count=8."""
+    import jax
+    n = len(jax.devices())
+    counts = [c for c in (1, 2, 4, 8) if c <= n]
+    return counts[:2] if quick else counts
+
+
+def _scaling_run(n_dev: int, mode: str, n_pts: int) -> float:
+    """Sharded assign over the first n_dev devices of this process (one
+    process holds the devices: no child process touches them)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.launch.mesh import use_mesh
+    sc = common.get_census()
+    xy, *_ = sc.sample_points(np.random.default_rng(7), n_pts)
+    pts = jnp.asarray(xy)
+    devs = np.array(jax.devices()[:n_dev])
+    if mode == "simple":
+        from repro.core.simple import SimpleConfig, SimpleIndex, assign_simple
+        idx = SimpleIndex.from_census(sc.census)
+        cfg = SimpleConfig(cap_state=0.5, cap_county=0.5, cap_block=0.5)
+        mesh = Mesh(devs.reshape(n_dev, 1), ("data", "model"))
+        pts = jax.device_put(pts, NamedSharding(mesh, P("data", None)))
+
+        def f(p):
+            return assign_simple(idx, p, cfg)[2]
+    else:
+        from repro.core.distributed import (assign_fast_distributed,
+                                            shard_covering)
+        from repro.core.fast import FastConfig
+        n_model = min(n_dev, 2)
+        mesh = Mesh(devs.reshape(max(n_dev // n_model, 1), n_model),
+                    ("data", "model"))
+        sidx = shard_covering(common.get_covering(9), sc.census,
+                              n_shards=n_model)
+        cfg = FastConfig(mode="exact", cap_boundary=0.5)
+
+        def f(p):
+            return assign_fast_distributed(sidx, p, mesh, cfg)[2]
     with use_mesh(mesh):
-        f = jax.jit(lambda p: assign_simple(idx, p, cfg)[2],
-                    in_shardings=jax.sharding.NamedSharding(
-                        mesh, jax.sharding.PartitionSpec("data", None)))
         f(pts).block_until_ready()
-        t0 = time.perf_counter(); f(pts).block_until_ready()
-        print("TIME", time.perf_counter() - t0)
-else:
-    from repro.core.distributed import shard_covering, assign_fast_distributed
-    from repro.core.fast import FastConfig
-    cov = common.get_covering(9)
-    n_model = min({n_dev}, 2)
-    mesh = make_test_mesh((max({n_dev}//n_model, 1), n_model))
-    sidx = shard_covering(cov, sc.census, n_shards=n_model)
-    cfg = FastConfig(mode="exact", cap_boundary=0.5)
-    with use_mesh(mesh):
-        f = jax.jit(lambda p: assign_fast_distributed(sidx, p, mesh, cfg)[2])
+        t0 = time.perf_counter()
         f(pts).block_until_ready()
-        t0 = time.perf_counter(); f(pts).block_until_ready()
-        print("TIME", time.perf_counter() - t0)
-"""
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True)
-    for line in out.stdout.splitlines():
-        if line.startswith("TIME"):
-            return float(line.split()[1])
-    raise RuntimeError(out.stderr[-1500:])
+        return time.perf_counter() - t0
 
 
 def fig5(quick=False):
-    """Paper Fig 5: simple-approach scaling with processing units.
-
-    NOTE: this container has ONE physical core; fake host devices validate
-    the sharded program structure (a real pod gives the paper's linear
-    scaling; the dry-run roofline covers the 256/512-chip projection)."""
+    """Paper Fig 5: simple-approach scaling with processing units, over
+    the devices this process holds (simulated CPU devices validate the
+    sharded program structure, not its speed)."""
     n = 100_000
-    for nd in ([1, 4] if quick else [1, 2, 4, 8]):
-        dt = _scaling_subprocess(nd, "simple", n)
+    for nd in _device_counts(quick):
+        dt = _scaling_run(nd, "simple", n)
         emit(f"fig5_simple_dev{nd}", dt * 1e6,
-             f"{n/dt:.0f} pts/s on {nd} simulated devices (1 phys core)")
+             f"{n/dt:.0f} pts/s on {nd} devices")
 
 
 # ------------------------------------------------------------------ fig6
@@ -123,12 +127,13 @@ def fig6(quick=False):
 # ------------------------------------------------------------------ fig7
 def fig7(quick=False):
     """Paper Fig 7: fast-approach thread scaling -> device scaling with the
-    Morton-sharded index (beyond-paper distribution)."""
+    Morton-sharded index (beyond-paper distribution), over the devices
+    this process holds."""
     n = 100_000
-    for nd in ([2, 4] if quick else [2, 4, 8]):
-        dt = _scaling_subprocess(nd, "fast", n)
+    for nd in [c for c in _device_counts(quick) if c >= 2]:
+        dt = _scaling_run(nd, "fast", n)
         emit(f"fig7_fast_dev{nd}", dt * 1e6,
-             f"{n/dt:.0f} pts/s on {nd} simulated devices (1 phys core)")
+             f"{n/dt:.0f} pts/s on {nd} devices")
 
 
 # ---------------------------------------------------------------- table1
@@ -221,6 +226,7 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true",
                     help="reduced sizes for CI")
     args = ap.parse_args()
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(SECTIONS)
     print("name,us_per_call,derived")
     for n in names:

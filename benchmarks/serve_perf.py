@@ -27,6 +27,7 @@ import numpy as np
 from benchmarks import common
 from repro.core.engine import EngineConfig, GeoEngine
 from repro.serving import GeoServer, ServeConfig
+from repro.compile_cache import enable_compile_cache
 
 N_POINTS = int(os.environ.get("BENCH_SERVE_N", 500_000))
 SMOKE_N = int(os.environ.get("BENCH_SERVE_SMOKE_N", 20_000))
@@ -126,6 +127,7 @@ def bench_serving(census, cov, requests, truths, buckets,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="verify-sized run: small stream, small buckets")

@@ -45,6 +45,7 @@ from benchmarks import common
 from repro.core.engine import EngineConfig, GeoEngine
 from repro.obs import Tracer
 from repro.serving import GeoServer, ServeConfig
+from repro.compile_cache import enable_compile_cache
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "results",
                         "BENCH_geo.json")
@@ -75,6 +76,7 @@ def run_pass(server, requests) -> float:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="verify-sized: smaller stream, fewer repeats")

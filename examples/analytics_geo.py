@@ -11,9 +11,11 @@ from repro.analytics import AnalyticsConfig, BlockAggregator
 from repro.core.engine import GeoEngine
 from repro.core.synth import build_synth_census
 from repro.serving import GeoServer, ServeConfig
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # 1. A census and an engine, as ever; the analytics mount is one
     #    config field.  window_s=8/slide_s=2 → sliding windows of 4
     #    panes; k_anon=5 suppresses any block seen by <5 distinct

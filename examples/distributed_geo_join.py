@@ -21,19 +21,21 @@ import numpy as np  # noqa: E402
 
 from repro.core.cells import build_cell_covering  # noqa: E402
 from repro.core.distributed import assign_fast_distributed, \
-    shard_covering  # noqa: E402
+    place_sharded, shard_covering  # noqa: E402
 from repro.core.engine import EngineConfig, GeoEngine  # noqa: E402
 from repro.core.fast import FastConfig  # noqa: E402
 from repro.core.synth import build_synth_census  # noqa: E402
 from repro.launch.mesh import make_test_mesh, use_mesh  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     sc = build_synth_census(seed=0, n_states=16, counties_per_state=8,
                             blocks_per_county=24)
     cov = build_cell_covering(sc.census, max_level=9)
     mesh = make_test_mesh((2, 4))       # ("data", "model")
-    sidx = shard_covering(cov, sc.census, n_shards=4)
+    sidx = place_sharded(shard_covering(cov, sc.census, n_shards=4), mesh)
     print(f"[dist] {len(cov.lo)} cells -> 4 Morton shards, "
           f"{sidx.index_bytes_per_shard()/1e6:.2f} MB/shard "
           f"(vs {cov.nbytes()/1e6:.2f} MB replicated)")

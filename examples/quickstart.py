@@ -11,6 +11,7 @@ import numpy as np
 
 from repro.core.engine import EngineConfig, GeoEngine
 from repro.core.synth import build_synth_census
+from repro.compile_cache import enable_compile_cache
 
 
 def timed_assign(engine, pts):
@@ -23,6 +24,7 @@ def timed_assign(engine, pts):
 
 
 def main():
+    enable_compile_cache()
     # 1. A synthetic census: 16 states / 128 counties / 3,072 block groups
     #    (same structure as the real data; see core/synth.py).
     print("building synthetic census...")

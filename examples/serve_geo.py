@@ -12,9 +12,11 @@ import numpy as np
 from repro.core.engine import EngineConfig, GeoEngine
 from repro.core.synth import build_synth_census
 from repro.serving import GeoServer, ServeConfig
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # 1. Build a census and a serving engine.  strategy="auto" lets the
     #    planner pick; max_delay_ms bounds how long a trickle request can
     #    sit in the queue before a flush fires (latency SLO).

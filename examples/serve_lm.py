@@ -13,6 +13,7 @@ from repro.configs.base import ModelConfig, RunConfig
 from repro.models.model import build_model
 from repro.models.module import init_params, param_count
 from repro.runtime.steps import make_serve_step
+from repro.compile_cache import enable_compile_cache
 
 CFG = ModelConfig(name="demo-serve-25m", family="dense", n_layers=6,
                   d_model=512, n_heads=8, n_kv_heads=4, d_ff=1408,
@@ -20,6 +21,7 @@ CFG = ModelConfig(name="demo-serve-25m", family="dense", n_layers=6,
 
 
 def main():
+    enable_compile_cache()
     run = RunConfig(remat="none", attn_chunk_q=64, attn_chunk_kv=64)
     model = build_model(CFG)
     params = init_params(model.specs, jax.random.key(0))

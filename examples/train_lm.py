@@ -18,6 +18,7 @@ from repro.models.module import init_params, param_count
 from repro.optim import adamw
 from repro.runtime.driver import DriverConfig, train_loop
 from repro.runtime.steps import make_train_step
+from repro.compile_cache import enable_compile_cache
 
 # ~103M params: 12L x 768d, llama-style.
 CFG = ModelConfig(name="demo-100m", family="dense", n_layers=12,
@@ -26,6 +27,7 @@ CFG = ModelConfig(name="demo-100m", family="dense", n_layers=12,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

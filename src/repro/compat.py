@@ -1,45 +1,25 @@
-"""Version-adaptive jax compatibility layer (DESIGN.md §12).
+"""The one mesh / shard_map import surface of the repo (DESIGN.md §12).
 
-One import surface for every mesh / shard_map consumer in the repo.  The
-installed jax may be 0.4.x (no ``jax.sharding.get_abstract_mesh``, no
-``jax.set_mesh``, no ``jax.shard_map``) or 0.5+ (all three public); the
-model stack and the geo engine's sharded strategies must run on both
-without touching version-specific symbols themselves.
+The installed jax is 0.9.0, which offers ``jax.set_mesh``,
+``jax.shard_map`` and ``jax.sharding.get_abstract_mesh`` publicly.  This
+module wraps them once, so the model stack and the geo engine's sharded
+strategies never touch mesh-context symbols themselves (GeoLint's
+``compat-boundary`` rule enforces that, DESIGN.md §17):
 
-Semantics:
-
-  * ``use_mesh(mesh)`` — context manager activating ``mesh``.  On new jax
-    it is exactly ``jax.set_mesh``.  On 0.4.x it records the mesh in a
+  * ``use_mesh(mesh)`` — context manager activating ``mesh``: it enters
+    ``jax.set_mesh`` and also records the *concrete* mesh in a
     context-local **ambient mesh** (a ``ContextVar``, so it nests and is
-    async/thread-safe) *and* enters the ``Mesh`` context manager, so both
-    ``shard_act``-style consumers and legacy bare-``PartitionSpec`` code
-    see it.
-  * ``get_abstract_mesh()`` — the active mesh or ``None``.  New jax:
-    ``jax.sharding.get_abstract_mesh()`` (empty mesh normalized to
-    ``None``).  Old jax: the ambient mesh, falling back to the
-    resource-env physical mesh so raw ``with Mesh(...):`` scopes (code
-    that never went through ``use_mesh``) still resolve.
+    async/thread-safe) — ``NamedSharding`` construction needs the
+    device-backed mesh, which the abstract context mesh does not carry.
+  * ``get_abstract_mesh()`` — the active mesh or ``None``: the ambient
+    concrete mesh, else the abstract mesh of a raw ``jax.set_mesh``
+    scope (the empty mesh normalized to ``None``).
   * ``shard_map(f, mesh=..., in_specs=..., out_specs=..., check_vma=...)``
-    — ``jax.shard_map`` on new jax; on 0.4.x the experimental
-    ``shard_map`` with ``check_vma`` translated to its old name
-    ``check_rep``.  ``mesh=None`` resolves the ambient mesh.
-  * ``with_sharding_constraint(x, spec, mesh=None)`` — activation
-    constraint that works on both: a concrete ``Mesh`` is wrapped into a
-    ``NamedSharding`` (0.4.x has no abstract-mesh constraint resolution),
-    an abstract mesh (new jax) uses the bare ``PartitionSpec``.
-
-Import this module — never ``jax.sharding.get_abstract_mesh`` /
-``jax.set_mesh`` / ``jax.shard_map`` directly — from any code that must
-run on the pinned 0.4.x toolchain (ROADMAP: supported-jax matrix).
-
-CAVEAT (0.4.x only): the ambient mesh is read at *trace* time and is NOT
-part of jit's cache key (new jax threads the abstract mesh through the
-tracing context precisely for this).  A jitted callable traced under one
-mesh scope and re-invoked under another (or under none) with the same
-avals silently reuses the first trace's constraints.  Rule: trace inside
-the ``use_mesh`` scope the executable will run under, and do not share
-one jitted callable across different mesh scopes — every in-repo caller
-(tests, launchers, benchmarks) follows this.
+    — ``jax.shard_map``; ``mesh=None`` resolves the active mesh and is a
+    loud error outside every mesh scope.
+  * ``with_sharding_constraint(x, spec, mesh=None)`` — a concrete
+    ``Mesh`` is wrapped into a ``NamedSharding``; an abstract mesh uses
+    the bare ``PartitionSpec``, which resolves against it inside jit.
 """
 from __future__ import annotations
 
@@ -48,144 +28,81 @@ from contextvars import ContextVar
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-# ------------------------------------------------------------- feature probes
-HAS_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
-HAS_SET_MESH = hasattr(jax, "set_mesh")
-HAS_PUBLIC_SHARD_MAP = hasattr(jax, "shard_map")
-
-try:                                        # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                         # pragma: no cover - older jax
-    AxisType = None
-
-if HAS_PUBLIC_SHARD_MAP:                    # pragma: no cover - newer jax
-    _shard_map_impl = jax.shard_map
-    _VMA_KWARG = "check_vma"
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _VMA_KWARG = "check_rep"
-
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 # ------------------------------------------------------------- ambient mesh
 _ambient_mesh: ContextVar[Optional[Mesh]] = ContextVar(
     "repro_ambient_mesh", default=None)
 
 
-def _resource_env_mesh() -> Optional[Mesh]:
-    """The physical mesh of the active ``with Mesh(...):`` scope, if any.
-
-    Private-API access is deliberately confined to this one function: it
-    is the 0.4.x fallback for callers that entered a raw ``Mesh`` context
-    manager instead of ``use_mesh``.
-    """
-    try:
-        from jax._src import mesh as _mesh_lib  # noqa: PLC0415
-        env_mesh = _mesh_lib.thread_resources.env.physical_mesh
-    except Exception:                       # pragma: no cover - API drift
-        return None
-    if env_mesh is None or env_mesh.empty:
-        return None
-    return env_mesh
-
-
 def get_abstract_mesh():
     """The active mesh, or None when no mesh scope is in effect.
 
-    The ambient ContextVar — recorded by :func:`use_mesh` on EVERY jax
-    generation, so the probes can never disagree — is consulted first;
-    then ``jax.sharding.get_abstract_mesh()`` where it exists (scopes
-    opened by a raw ``jax.set_mesh`` that bypassed ``use_mesh``; the
-    empty mesh normalizes to None); last the 0.4.x resource-env mesh
-    (raw ``with Mesh(...):`` scopes).
+    The ambient ContextVar (recorded by :func:`use_mesh`) is consulted
+    first; then ``jax.sharding.get_abstract_mesh()`` for scopes opened by
+    a raw ``jax.set_mesh`` that bypassed ``use_mesh`` (the empty mesh
+    normalizes to None).
     """
     m = _ambient_mesh.get()
     if m is not None:
         return m
-    if HAS_ABSTRACT_MESH:                   # pragma: no cover - newer jax
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    return _resource_env_mesh()
+    m = jax.sharding.get_abstract_mesh()
+    if m is not None and m.axis_names:
+        return m
+    return None
 
 
 @contextlib.contextmanager
-def _ambient_scope(mesh: Mesh):
+def use_mesh(mesh: Mesh):
+    """Context manager activating ``mesh`` for everything underneath it:
+    ``jax.set_mesh`` plus the ambient concrete mesh (queried by
+    ``models.layers.shard_act``, ``shard_map(mesh=None)`` and
+    :func:`concrete_mesh`)."""
     token = _ambient_mesh.set(mesh)
     try:
-        if HAS_SET_MESH:                    # pragma: no cover - newer jax
-            with jax.set_mesh(mesh):
-                yield mesh
-        else:
-            # Enter the Mesh context manager so the resource env is set:
-            # legacy code inside the scope may still use bare
-            # PartitionSpecs (pjit in-axis-resources style) that resolve
-            # against it.
-            with mesh:
-                yield mesh
+        with jax.set_mesh(mesh):
+            yield mesh
     finally:
         _ambient_mesh.reset(token)
-
-
-def use_mesh(mesh: Mesh):
-    """Context manager activating ``mesh`` for everything underneath it.
-
-    The concrete mesh is always recorded in the ambient ContextVar
-    (queried by ``models.layers.shard_act``, ``shard_map(mesh=None)``
-    and :func:`concrete_mesh`); underneath that, jax >= 0.5 enters
-    ``jax.set_mesh`` and 0.4.x enters the ``Mesh`` resource-env scope.
-    """
-    return _ambient_scope(mesh)
 
 
 def concrete_mesh() -> Optional[Mesh]:
     """The active *concrete* ``Mesh`` (device-backed), or None.
 
     ``NamedSharding`` construction (checkpoint restore, param shardings)
-    needs real devices, which the new-jax abstract mesh does not carry —
-    hence ``use_mesh`` recording the concrete mesh on every version.
+    needs real devices, which the abstract context mesh does not carry —
+    hence ``use_mesh`` recording the concrete mesh.
     """
-    return _ambient_mesh.get() or _resource_env_mesh()
+    return _ambient_mesh.get()
 
 
 # ----------------------------------------------------------------- shard_map
 def shard_map(f, *, mesh=None, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` surface on every supported jax version.
-
-    ``check_vma`` is the new-jax name for replication checking; on 0.4.x
-    it is forwarded as ``check_rep``.  ``mesh=None`` resolves the ambient
-    mesh (new jax resolves it natively).
-    """
+    """``jax.shard_map``; ``mesh=None`` resolves the active mesh."""
     if mesh is None:
         mesh = get_abstract_mesh()
-        if mesh is None and not HAS_PUBLIC_SHARD_MAP:
-            # New jax can still resolve mesh=None natively (set_mesh
-            # scopes that bypassed use_mesh); old jax cannot.
+        if mesh is None:
             raise ValueError(
                 "shard_map: no mesh argument and no ambient mesh active "
                 "(wrap the call in repro.compat.use_mesh(...))")
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_VMA_KWARG: check_vma})
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 # ------------------------------------------------------------------ builders
 def make_mesh(shape, axes) -> Mesh:
-    """jax.make_mesh with explicit Auto axis types where supported."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,          # pragma: no cover - newer jax
+    """jax.make_mesh with explicit Auto axis types."""
+    return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 
 
 # --------------------------------------------------------------- constraints
 def with_sharding_constraint(x, spec: PartitionSpec, mesh=None):
-    """Activation-sharding constraint valid on both jax generations.
+    """Activation-sharding constraint under the active (or given) mesh.
 
-    ``mesh=None`` resolves the ambient mesh; no active mesh makes this a
+    ``mesh=None`` resolves the active mesh; no active mesh makes this a
     no-op (CPU smoke tests).  A concrete ``Mesh`` becomes a
-    ``NamedSharding`` (0.4.x cannot resolve a bare PartitionSpec outside
-    a resource-env scope); an abstract mesh (new jax) takes the bare
+    ``NamedSharding``; an abstract mesh takes the bare
     ``PartitionSpec``, which resolves against it inside jit.
     """
     if mesh is None:
@@ -194,4 +111,4 @@ def with_sharding_constraint(x, spec: PartitionSpec, mesh=None):
         return x
     if isinstance(mesh, Mesh):
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-    return jax.lax.with_sharding_constraint(x, spec)  # pragma: no cover
+    return jax.lax.with_sharding_constraint(x, spec)

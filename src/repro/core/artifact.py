@@ -45,7 +45,8 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from repro.core.cells import CellCovering, build_cell_covering
-from repro.core.distributed import ShardedFastIndex, shard_covering
+from repro.core.distributed import (ShardedFastIndex, place_sharded,
+                                    shard_covering)
 from repro.core.fast import FastIndex
 from repro.core.geometry import CensusMap, PolygonSoup
 from repro.core.simple import SimpleIndex
@@ -156,10 +157,13 @@ class GeoIndexSet:
             raise ValueError(f"building {what} needs a census "
                              f"(GeoIndexSet built from arrays only?)")
 
-    def sharded_index(self, n_shards: int,
-                      with_pool: bool = False) -> ShardedFastIndex:
+    def sharded_index(self, n_shards: int, with_pool: bool = False,
+                      mesh=None) -> ShardedFastIndex:
         """The Morton-sharded index for ``n_shards``, built once per
-        shard count (pool attached on demand, like ``ensure``)."""
+        shard count (pool attached on demand, like ``ensure``).  With a
+        ``mesh``, the cached index is laid out on it (``place_sharded``:
+        one table shard per device of its "model" axis), so repeat calls
+        on that mesh hand jit arrays that are already in place."""
         if n_shards not in self.sharded:
             if self.covering is None or self.census is None:
                 raise ValueError("assign_sharded needs the engine built "
@@ -172,6 +176,9 @@ class GeoIndexSet:
             self.sharded[n_shards] = dataclasses.replace(
                 sidx, edge_pool=ops.build_edge_pool(
                     np.asarray(sidx.block_edges), be=self.pool_be()))
+        if mesh is not None:
+            self.sharded[n_shards] = place_sharded(self.sharded[n_shards],
+                                                   mesh)
         return self.sharded[n_shards]
 
     # -- autotune record ----------------------------------------------------

@@ -22,6 +22,7 @@ trie (DESIGN.md §2).
 from __future__ import annotations
 
 import dataclasses
+import os
 
 import numpy as np
 
@@ -92,8 +93,77 @@ class CellCovering:
 def build_cell_covering(census: CensusMap, max_level: int = 9,
                         max_cand: int = 8,
                         min_split_level: int = 2) -> CellCovering:
-    """Build the global covering over the census *block* level."""
+    """Build the global covering over the census *block* level.
+
+    The walk is host Python, one node at a time; it dominates build time
+    at fine levels, where the boundary cells it visits double with each
+    level.  From ``PARALLEL_MIN_LEVEL`` on, when this process may run on
+    more than one core, the subtrees below level ``PARALLEL_LEVEL`` are
+    walked in spawned host processes (numpy only, no JAX) and their cells
+    spliced back in walk order, so the result is identical to the
+    one-process build."""
     assert max_level <= 15, "leaf codes must fit int32"
+    ctx = _walk_context(census, max_level, max_cand, min_split_level)
+    root = (0, 0, 0, np.arange(census.blocks.n_poly, dtype=np.int32),
+            np.arange(len(ctx["ex1"]), dtype=np.int32))
+    workers = _host_workers()
+    if workers > 1 and max_level >= PARALLEL_MIN_LEVEL:
+        seq, tasks = _walk(ctx, [root], split_level=PARALLEL_LEVEL)
+        import multiprocessing  # noqa: PLC0415 - only the parallel path
+        with multiprocessing.get_context("spawn").Pool(
+                workers, initializer=_init_worker, initargs=(ctx,)) as pool:
+            parts = pool.map(_walk_task, tasks, chunksize=1)
+        cells = []
+        for rec in seq:
+            cells.extend(parts[rec] if isinstance(rec, int) else [rec])
+    else:
+        cells, _ = _walk(ctx, [root])
+
+    out_lo, out_hi, out_val, out_lvl = [], [], [], []
+    cand_rows: list[np.ndarray] = []
+    for lo, hi, lvl, owner, row in cells:
+        if row is not None:            # boundary cell: next candidate row
+            owner = -(len(cand_rows) + 1)
+            cand_rows.append(row)
+        out_lo.append(lo)
+        out_hi.append(hi)
+        out_val.append(owner)
+        out_lvl.append(lvl)
+
+    order = np.argsort(np.asarray(out_lo))
+    lo = np.asarray(out_lo, np.int32)[order]
+    hi = np.asarray(out_hi, np.int32)[order]
+    val = np.asarray(out_val, np.int32)[order]
+    lvl = np.asarray(out_lvl, np.int8)[order]
+    cand = (np.stack(cand_rows) if cand_rows
+            else np.zeros((0, max_cand), np.int32))
+    cov = CellCovering(lo=lo, hi=hi, val=val, level=lvl, cand=cand,
+                       max_level=max_level, extent=census.extent,
+                       n_interior=int((val >= 0).sum()),
+                       n_boundary=len(cand_rows))
+    return cov
+
+
+# Level whose nodes become the tasks of a parallel build (4^4 = 256 at
+# most: enough to balance a dozen workers, few enough to stay cheap).
+PARALLEL_LEVEL = 4
+# Coverings this deep are walked in parallel: below it the walk takes
+# seconds, about what spawning the workers costs.
+PARALLEL_MIN_LEVEL = 10
+# At most this many walk processes (a shared host may list many more
+# cores than it grants).
+MAX_WORKERS = 16
+
+
+def _host_workers() -> int:
+    """Walk processes for a parallel build: the cores this process may
+    run on, at most ``MAX_WORKERS``."""
+    return min(len(os.sched_getaffinity(0)), MAX_WORKERS)
+
+
+def _walk_context(census: CensusMap, max_level: int, max_cand: int,
+                  min_split_level: int) -> dict:
+    """Normalized block geometry the covering walk reads (host, numpy)."""
     x0, x1, y0, y1 = census.extent
     sx, sy = 1.0 / (x1 - x0), 1.0 / (y1 - y0)
     blocks = census.blocks
@@ -108,14 +178,34 @@ def build_cell_covering(census: CensusMap, max_level: int = 9,
     keep = ~np.all(e1 == e2, axis=-1)
     poly_of_edge = np.broadcast_to(
         np.arange(blocks.n_poly, dtype=np.int32)[:, None], keep.shape)[keep]
-    ex1, ey1 = e1[keep][:, 0], e1[keep][:, 1]
-    ex2, ey2 = e2[keep][:, 0], e2[keep][:, 1]
 
     nbb = blocks.bbox.astype(np.float64).copy()
     nbb[:, 0:2] = (nbb[:, 0:2] - x0) * sx
     nbb[:, 2:4] = (nbb[:, 2:4] - y0) * sy
+    return dict(
+        ex1=e1[keep][:, 0], ey1=e1[keep][:, 1],
+        ex2=e2[keep][:, 0], ey2=e2[keep][:, 1],
+        poly_of_edge=poly_of_edge, nbb=nbb,
+        rings_n=[verts[p, :blocks.n_verts[p]]
+                 for p in range(blocks.n_poly)],
+        max_level=max_level, max_cand=max_cand,
+        min_split_level=min_split_level)
 
-    rings_n = [verts[p, :blocks.n_verts[p]] for p in range(blocks.n_poly)]
+
+def _walk(ctx: dict, stack: list, split_level: int | None = None):
+    """Depth-first covering walk from ``stack``.
+
+    Returns (cells, tasks): cells in walk order as (lo, hi, level,
+    owner, cand_row) with ``cand_row`` set for boundary cells (their
+    value is assigned by the caller, in walk order).  With
+    ``split_level``, each node reached at that level is not walked: it
+    is appended to ``tasks`` and its index stands in ``cells`` where its
+    subtree's cells belong."""
+    ex1, ey1, ex2, ey2 = ctx["ex1"], ctx["ey1"], ctx["ex2"], ctx["ey2"]
+    nbb, rings_n = ctx["nbb"], ctx["rings_n"]
+    poly_of_edge = ctx["poly_of_edge"]
+    max_level, max_cand = ctx["max_level"], ctx["max_cand"]
+    min_split_level = ctx["min_split_level"]
 
     def center_owner(cx, cy, cand_polys):
         for p in cand_polys:
@@ -124,15 +214,16 @@ def build_cell_covering(census: CensusMap, max_level: int = 9,
                 return int(p)
         return -1
 
-    out_lo, out_hi, out_val, out_lvl = [], [], [], []
-    cand_rows: list[np.ndarray] = []
-
-    all_polys = np.arange(blocks.n_poly, dtype=np.int32)
-    all_edges = np.arange(len(ex1), dtype=np.int32)
-    # BFS stack: (level, ix, iy, candidate polys, candidate edges)
-    stack = [(0, 0, 0, all_polys, all_edges)]
+    cells: list = []
+    tasks: list = []
+    # DFS stack: (level, ix, iy, candidate polys, candidate edges)
     while stack:
-        l, ix, iy, cpolys, cedges = stack.pop()
+        node = stack.pop()
+        l, ix, iy, cpolys, cedges = node
+        if l == split_level:
+            cells.append(len(tasks))
+            tasks.append(node)
+            continue
         size = 1.0 / (1 << l)
         rx0, ry0 = ix * size, iy * size
         rx1, ry1 = rx0 + size, ry0 + size
@@ -151,10 +242,8 @@ def build_cell_covering(census: CensusMap, max_level: int = 9,
             owner = center_owner((rx0 + rx1) / 2, (ry0 + ry1) / 2, cpolys)
             if owner < 0:
                 continue  # cell fully outside the map
-            out_lo.append(m << shift)
-            out_hi.append(((m + 1) << shift) - 1)
-            out_val.append(owner)
-            out_lvl.append(l)
+            cells.append((m << shift, ((m + 1) << shift) - 1, l, owner,
+                          None))
         elif l == max_level:
             # Boundary cell: candidates = polys owning any crossing edge,
             # plus the centre owner (listed first for approximate mode).
@@ -167,26 +256,22 @@ def build_cell_covering(census: CensusMap, max_level: int = 9,
                 continue
             row = np.full(max_cand, -1, np.int32)
             row[:len(cands)] = cands
-            out_lo.append(m << shift)
-            out_hi.append(((m + 1) << shift) - 1)
-            out_val.append(-(len(cand_rows) + 1))
-            out_lvl.append(l)
-            cand_rows.append(row)
+            cells.append((m << shift, ((m + 1) << shift) - 1, l, None,
+                          row))
         else:
             for dy in (0, 1):
                 for dx in (0, 1):
                     stack.append((l + 1, 2 * ix + dx, 2 * iy + dy,
                                   cpolys, cedges))
+    return cells, tasks
 
-    order = np.argsort(np.asarray(out_lo))
-    lo = np.asarray(out_lo, np.int32)[order]
-    hi = np.asarray(out_hi, np.int32)[order]
-    val = np.asarray(out_val, np.int32)[order]
-    lvl = np.asarray(out_lvl, np.int8)[order]
-    cand = (np.stack(cand_rows) if cand_rows
-            else np.zeros((0, max_cand), np.int32))
-    cov = CellCovering(lo=lo, hi=hi, val=val, level=lvl, cand=cand,
-                       max_level=max_level, extent=census.extent,
-                       n_interior=int((val >= 0).sum()),
-                       n_boundary=len(cand_rows))
-    return cov
+
+_WORKER_CTX: dict = {}
+
+
+def _init_worker(ctx: dict) -> None:
+    _WORKER_CTX.update(ctx)
+
+
+def _walk_task(node) -> list:
+    return _walk(_WORKER_CTX, [node])[0]

@@ -14,17 +14,19 @@ ranges across the "model" axis while points stay batch-sharded across
     fixed-capacity compaction, so exact-mode compute is also sharded.
 
 ``shard_covering`` splits a host-side CellCovering into equal-cell padded
-slices; ``assign_fast_distributed`` is the shard_map lookup.
+slices; ``place_sharded`` lays them out one shard per device of a mesh;
+``assign_fast_distributed`` is the shard_map lookup.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as PS
+from jax.sharding import NamedSharding, PartitionSpec as PS
 
 from repro.core.cells import CellCovering
 from repro.core.fast import (FastConfig, extent_mask, quant_for_extent,
@@ -131,6 +133,31 @@ def shard_covering(cov: CellCovering, census: CensusMap,
         max_level=cov.max_level, n_shards=n_shards)
 
 
+STACKED = ("cell_lo", "cell_hi", "cell_val", "cand")
+
+
+def place_sharded(idx: ShardedFastIndex, mesh) -> ShardedFastIndex:
+    """``idx`` laid out on ``mesh``: the stacked per-shard tables
+    (``STACKED``) split one shard per device along the "model" axis,
+    every other array replicated.  Arrays already laid out so stay where
+    they are, so placing a placed index moves nothing."""
+    if mesh.shape["model"] != idx.n_shards:
+        raise ValueError(f"a {idx.n_shards}-shard index cannot be placed "
+                         f"on a 'model' axis of {mesh.shape['model']}")
+    split = NamedSharding(mesh, PS("model"))
+    whole = NamedSharding(mesh, PS())
+
+    def put(name, tree):
+        want = split if name in STACKED else whole
+        return jax.tree.map(
+            lambda a: a if getattr(a, "sharding", None) == want
+            else jax.device_put(a, want), tree)
+
+    return dataclasses.replace(idx, **{
+        f.name: put(f.name, getattr(idx, f.name))
+        for f in dataclasses.fields(idx) if not f.metadata.get("static")})
+
+
 def local_lookup(block_edges, lo, hi, val, cand, codes, points,
                  mode: str, cap: int, backend, active=None,
                  edge_pool=None):
@@ -164,11 +191,14 @@ def local_lookup(block_edges, lo, hi, val, cand, codes, points,
     return bid, rs
 
 
+@functools.partial(jax.jit, static_argnames=("mesh", "cfg"))
 def assign_fast_distributed(idx: ShardedFastIndex, points: jnp.ndarray,
                             mesh, cfg: FastConfig = FastConfig()):
     """Sharded-index lookup under shard_map.  points [N, 2] batch-sharded
     over ("pod","data"); index sharded over "model".  Returns
-    (sid, cid, bid, stats) exactly like assign_fast."""
+    (sid, cid, bid, stats) exactly like assign_fast.  Jitted with the
+    index as an argument, so its tables are never baked into the
+    program and repeat calls reuse one compile."""
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     dp_size = int(np.prod([mesh.shape[a] for a in dp])) if dp else 1
     n = points.shape[0]
@@ -205,6 +235,9 @@ def assign_fast_distributed(idx: ShardedFastIndex, points: jnp.ndarray,
         in_specs=(PS(bspec, None), PS("model", None), PS("model", None),
                   PS("model", None), PS("model", None, None), PS("model")),
         out_specs=(PS(bspec), PS(), PS(), PS()),
+        # Pallas kernels in the body declare no per-axis variance (vma)
+        # for their outputs, which the check would require.
+        check_vma=False,
     )(points, idx.cell_lo, idx.cell_hi, idx.cell_val, idx.cand,
       idx.range_lo)
     cid = jnp.where(bid >= 0, idx.block_parent[jnp.clip(bid, 0, None)], -1)

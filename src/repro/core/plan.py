@@ -9,10 +9,14 @@ the index outgrows one device.  The deployment follow-up (Samuel et al.,
 arXiv:2108.11525) shows those regimes shifting live — so the choice
 belongs in a planner, not in caller code.
 
-``plan_for`` inspects four signals and emits a ``GeoPlan``:
+``plan_for`` inspects these signals and emits a ``GeoPlan``:
 
-  * **device kind** (``jax.default_backend()``) — the fused gather-PIP
+  * **platform** (``jax.default_backend()``) — the fused gather-PIP
     kernel is a TPU bandwidth win; on CPU the ref path is faster;
+  * **kernel backend** (``cfg.backend``, resolved as the kernels resolve
+    it) — on compiled Pallas, what a TPU runs, the one-pass cascade is
+    never planned: its kernel does not compile for the TPU
+    (``strategies.onepass_runs``);
   * **batch size hint** — a batch smaller than ``SMALL_BATCH`` doesn't
     amortize the covering BFS if no covering exists yet;
   * **index capabilities** (``GeoIndexSet.capabilities()``) — replanning
@@ -25,7 +29,8 @@ belongs in a planner, not in caller code.
     the fast path's flat candidate lists;
   * **recorded autotune** (``GeoIndexSet.tuning``, written by
     ``geo_perf --autotune``) — when the artifact carries a measured
-    winner for this device kind, that measurement overrides the
+    winner for this device kind (``jax.devices()[0].device_kind``, which
+    tells chip generations apart), that measurement overrides the
     threshold heuristics above: a recorded ``fast_onepass`` win routes
     straight to the one-pass fused cascade at its tuned edge-pool block
     size.
@@ -43,6 +48,8 @@ from typing import Optional, Tuple, Union
 
 import jax
 import numpy as np
+
+from repro.core.strategies import onepass_runs
 
 # Planner thresholds (see DESIGN.md §11 for the rationale and how to
 # retune them from bench rows).
@@ -80,7 +87,8 @@ class GeoPlan:
     fused: Union[bool, str] = False   # False | True | "onepass"
     sharded: bool = False
     n_shards: int = 1
-    device_kind: str = "cpu"
+    platform: str = "cpu"             # jax.default_backend(): what runs
+    device_kind: str = "cpu"          # jax.devices()[0].device_kind
     n_points: Optional[int] = None
     boundary_fraction: Optional[float] = None
     auto: bool = True
@@ -91,7 +99,8 @@ class GeoPlan:
         return {
             "strategy": self.strategy, "mode": self.mode,
             "fused": self.fused, "sharded": self.sharded,
-            "n_shards": self.n_shards, "device_kind": self.device_kind,
+            "n_shards": self.n_shards, "platform": self.platform,
+            "device_kind": self.device_kind,
             "n_points": (None if self.n_points is None
                          else int(self.n_points)),
             "boundary_fraction": (None if self.boundary_fraction is None
@@ -119,21 +128,28 @@ def covering_boundary_fraction(covering) -> float:
     return float(span[val < 0].sum() / total)
 
 
-def explicit_plan(strategy: str, cfg, device_kind: str = None) -> GeoPlan:
+def default_device() -> Tuple[str, str]:
+    """(platform, device_kind) of the device plans are made for."""
+    return jax.default_backend(), jax.devices()[0].device_kind
+
+
+def explicit_plan(strategy: str, cfg) -> GeoPlan:
     """The degenerate plan recording a caller-pinned strategy, so
     ``engine.explain()`` has one answer shape whether or not the planner
     ran."""
+    platform, device_kind = default_device()
     return GeoPlan(strategy=strategy, mode=cfg.mode,
                    # fast_onepass pins the one-pass kernel regardless of
                    # what the config says — record what actually runs.
                    fused=("onepass" if strategy == "fast_onepass"
                           else cfg.fused),
-                   device_kind=device_kind or jax.default_backend(),
+                   platform=platform, device_kind=device_kind,
                    auto=False, reasons=("explicit strategy request",))
 
 
 def plan_for(cfg, *, covering=None, capabilities: Optional[dict] = None,
              n_points: Optional[int] = None,
+             platform: Optional[str] = None,
              device_kind: Optional[str] = None,
              n_devices: Optional[int] = None,
              tuning: Optional[dict] = None) -> GeoPlan:
@@ -144,9 +160,15 @@ def plan_for(cfg, *, covering=None, capabilities: Optional[dict] = None,
     constrains the plan to what an existing artifact can execute.
     ``tuning`` is the artifact's recorded autotune block
     (``GeoIndexSet.tuning``) — a measured winner there beats the
-    threshold heuristics.
+    threshold heuristics.  ``platform`` decides the kernel data path
+    (fused gather-PIP on a TPU); ``device_kind`` only matches tuning
+    records.  Both default to the first local device.  Whether the
+    one-pass cascade can run follows ``cfg.backend``.
     """
-    device_kind = device_kind or jax.default_backend()
+    if platform is None or device_kind is None:
+        here = default_device()
+        platform = platform or here[0]
+        device_kind = device_kind or here[1]
     n_devices = n_devices if n_devices is not None \
         else jax.local_device_count()
     fresh = capabilities is None
@@ -166,10 +188,16 @@ def plan_for(cfg, *, covering=None, capabilities: Optional[dict] = None,
     tune = dict(tuning or {})
     # A recorded autotune win only transfers within its measurement
     # context: same device kind (a CPU-recorded winner says nothing
-    # about TPU DMA behaviour, and vice versa).
+    # about TPU DMA behaviour, one chip generation nothing about
+    # another).  On compiled Pallas kernels the one-pass kernel cannot
+    # run at all.
     tuned_onepass = (tune.get("winner") == "fast_onepass"
                      and tune.get("device_kind", device_kind)
                      == device_kind)
+    if tuned_onepass and not onepass_runs(cfg.backend):
+        tuned_onepass = False
+        reasons.append("recorded fast_onepass win ignored: the one-pass "
+                       "cascade kernel does not compile for the TPU")
 
     # -- strategy -----------------------------------------------------------
     if not has_cell_index:
@@ -245,18 +273,18 @@ def plan_for(cfg, *, covering=None, capabilities: Optional[dict] = None,
                        if fused else
                        "fused requested but unusable here (no candidate "
                        "PIP or no edge pool built): dropped")
-    elif device_kind == "tpu" and runs_candidate_pip and pool_available:
+    elif platform == "tpu" and runs_candidate_pip and pool_available:
         fused = True
         reasons.append("TPU device: fused gather-PIP removes the "
                        "gathered-edges HBM round trip")
     else:
         fused = False
-        if runs_candidate_pip and device_kind == "tpu":
+        if runs_candidate_pip and platform == "tpu":
             reasons.append("TPU device but no edge pool built for this "
                            "index: fused unusable, running the gather "
                            "path")
         elif runs_candidate_pip:
-            reasons.append(f"device {device_kind!r}: the legacy gather "
+            reasons.append(f"platform {platform!r}: the legacy gather "
                            f"path wins off-TPU")
 
     # -- sharding recommendation --------------------------------------------
@@ -271,6 +299,7 @@ def plan_for(cfg, *, covering=None, capabilities: Optional[dict] = None,
 
     return GeoPlan(strategy=strategy, mode=mode, fused=fused,
                    sharded=sharded, n_shards=n_shards,
-                   device_kind=device_kind, n_points=n_points,
+                   platform=platform, device_kind=device_kind,
+                   n_points=n_points,
                    boundary_fraction=bf, auto=True,
                    reasons=tuple(reasons))

@@ -50,6 +50,21 @@ class SimpleStrategy(Strategy):
             extra=st))
 
 
+ONEPASS_ON_TPU = (
+    "the one-pass cascade kernel (kernels/cascade.py) does not compile "
+    "for the TPU: Mosaic refuses its one-row point and output blocks and "
+    "its scalar reads at dynamic indices out of VMEM-resident tables "
+    "(ROADMAP §2).  Use strategy 'fast' with fused=True (gather-PIP), or "
+    "choose the ref or interpret kernel backend explicitly")
+
+
+def onepass_runs(backend: str | None = None) -> bool:
+    """Whether the one-pass cascade can run on kernel ``backend`` (None:
+    the one ``ops.resolve_backend`` picks): everywhere but compiled
+    Pallas, for which it does not compile (``ONEPASS_ON_TPU``)."""
+    return ops.resolve_backend(backend) != "pallas"
+
+
 @register_strategy("fast", needs=("fast",), needs_edge_pool=True)
 class FastStrategy(Strategy):
     """The paper's §IV true-hit-filter cell index (cfg.mode picks exact /
@@ -59,6 +74,14 @@ class FastStrategy(Strategy):
         # Only exact mode runs candidate PIP on the fast index (approx
         # accepts the centre owner), so only it needs the edge pool.
         return ("fast",) if cfg.fused and cfg.mode == "exact" else ()
+
+    def runs_onepass(self, cfg) -> bool:
+        return cfg.fused == "onepass" and cfg.mode == "exact"
+
+    def validate(self, indices, cfg) -> None:
+        super().validate(indices, cfg)
+        if self.runs_onepass(cfg) and not onepass_runs(cfg.backend):
+            raise ValueError(f"strategy {self.name!r}: {ONEPASS_ON_TPU}")
 
     def assign(self, indices, points, cfg) -> AssignResult:
         sid, cid, bid, st = fast_mod.assign_fast(
@@ -83,6 +106,9 @@ class FastOnepassStrategy(FastStrategy):
         # Always exact, always the in-kernel candidate walk: the edge
         # pool is unconditionally required (and validated at build).
         return ("fast",)
+
+    def runs_onepass(self, cfg) -> bool:
+        return True
 
     def assign(self, indices, points, cfg) -> AssignResult:
         fcfg = dataclasses.replace(cfg.fast_cfg(), mode="exact",
@@ -156,6 +182,8 @@ class HybridStrategy(Strategy):
             overflow=st["overflow"], extra=st))
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("mesh", "cfg", "capacity", "cap_pip"))
 def _sharded_assign(sidx: ShardedFastIndex, points: jnp.ndarray, mesh,
                     cfg: FastConfig, capacity: int, cap_pip: int):
     """Dispatch-routed sharded lookup: bucket points by owning Morton
@@ -199,6 +227,9 @@ def _sharded_assign(sidx: ShardedFastIndex, points: jnp.ndarray, mesh,
         in_specs=(ps("model"), ps("model"), ps("model"), ps("model"),
                   ps("model"), ps("model")),
         out_specs=(ps("model"), ps(), ps(), ps(), ps()),
+        # Pallas kernels in the body declare no per-axis variance (vma)
+        # for their outputs, which the check would require.
+        check_vma=False,
     )(buf_pts, buf_ok, sidx.cell_lo, sidx.cell_hi, sidx.cell_val,
       sidx.cand)
 
@@ -229,7 +260,8 @@ class ShardedStrategy(Strategy):
         n = points.shape[0]
         n_shards = int(mesh.shape["model"])
         sidx = indices.sharded_index(
-            n_shards, with_pool=(cfg.fused and cfg.mode == "exact"))
+            n_shards, with_pool=(cfg.fused and cfg.mode == "exact"),
+            mesh=mesh)
         capacity = capacity_for(n, cfg.cap_shard / n_shards)
         cap_pip = capacity_for(capacity, cfg.cap_boundary,
                                ceiling=capacity)

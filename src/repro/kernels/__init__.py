@@ -3,7 +3,5 @@
 # kernel. Leave this package empty if the paper has none.
 from jax.experimental.pallas import tpu as _pltpu
 
-# jax-version compat: jax < 0.5 names the Mosaic params TPUCompilerParams,
-# newer jax CompilerParams.  Every kernel module imports it from here.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) \
-    or getattr(_pltpu, "TPUCompilerParams")
+# Mosaic compiler params; every kernel module imports them from here.
+CompilerParams = _pltpu.CompilerParams

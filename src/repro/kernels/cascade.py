@@ -29,7 +29,7 @@ Per grid step (one point):
 The candidate DMA is *data dependent* (the block range comes from the
 in-kernel cell lookup), which a BlockSpec index map cannot express —
 index maps run before the body.  Hence the manual ``make_async_copy``
-double buffering; the pool stays in ``TPUMemorySpace.ANY`` (HBM) and
+double buffering; the pool stays in ``pl.ANY`` (HBM) and
 only the blocks a boundary point actually needs ever cross into VMEM.
 Unlike the BlockSpec pipeline in kernels/gather_pip.py there is no
 automatic revisit-skip across points, but interior points issue zero
@@ -244,12 +244,12 @@ def assign_cascade(points, quant, cell_lo, cell_hi, cell_val, top_start,
         grid=(n,),
         in_specs=[
             pl.BlockSpec((1, 2), lambda i: (i, 0)),             # point
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.SMEM),  # quant
+            pl.BlockSpec(memory_space=pltpu.SMEM),               # quant
             _whole(cell_lo.shape), _whole(cell_hi.shape),
             _whole(cell_val.shape), _whole(top_start.shape),
             _whole(cand.shape), _whole(bbox.shape),
             _whole(first.shape), _whole(count.shape),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),   # pool
+            pl.BlockSpec(memory_space=pl.ANY),                     # pool
         ],
         out_specs=tuple(pl.BlockSpec((1, 1), lambda i: (i, 0))
                         for _ in range(4)),

@@ -23,6 +23,9 @@ Data layout (``EdgePool``, built host-side by ``build_edge_pool``):
     polygon ``p`` owns pool blocks ``first[p] .. first[p]+count[p]-1``.
 
 Kernel schedule: grid ``(R, max_blocks)``, one point per grid row.  The
+point and output blocks are the ``ROWS``-row sublane group holding row
+``r`` (revisited by its ROWS consecutive grid rows); the step reads and
+writes only row ``r % ROWS``.  The
 scalar-prefetched ``(first, nblk)`` tables are available before the body
 runs, so the pool's index map picks block ``first[r] + b`` (clamped to the
 last owned block when ``b >= nblk[r]``; the ``@pl.when`` guard keeps the
@@ -30,6 +33,8 @@ over-range steps from accumulating).  Pallas double-buffers the block DMA
 across grid steps and skips the fetch entirely when consecutive steps map
 to the same block — candidate ids sorted (or merely spatially correlated,
 as compacted boundary buffers are) amortize to near-zero edge traffic.
+The prefetched tables sit in SMEM, which bounds R per call
+(``MAX_ROWS``).
 
 The trade: one point per step uses 1 of 8 sublanes, but the op is
 bandwidth-bound — eliminating the HBM materialization beats lane
@@ -57,6 +62,14 @@ from repro.kernels import CompilerParams
 # default trades padding waste (small polygons zero-fill one block) against
 # grid steps for large polygons (ceil(E / BE) blocks each).
 DEF_BE = 256
+# Point / output rows per block: the f32 sublane tile.  A grid step
+# still tests one point; it reads its row out of the ROWS-row block.
+ROWS = 8
+# Most points per call.  The scalar-prefetched ``first``/``nblk`` [R] i32
+# tables live in SMEM (1 MiB on TPU v5e): 2 x 4 B x 65,536 = 512 KiB
+# compiles, 262,144 rows does not.  ops.pip_candidates splits larger
+# batches into calls of this size.
+MAX_ROWS = 1 << 16
 
 
 @jax.tree_util.register_pytree_node_class
@@ -122,19 +135,23 @@ def build_edge_pool(edges: np.ndarray, be: int = DEF_BE) -> EdgePool:
 
 
 def _gather_pip_kernel(first_ref, nblk_ref, pts_ref, blk_ref, out_ref):
-    """One point vs one prefetched edge block: pts [1, 2], blk [1, 4, BE],
-    out [1, 1] i32 accumulated across the block axis of the grid."""
+    """One point vs one prefetched edge block: pts [ROWS, 2] and out
+    [ROWS, 1] i32 hold the point's sublane group (Mosaic tiles the
+    second-minor axis by 8, so a block cannot be one row); the step
+    reads and accumulates row ``r % ROWS`` across the block axis of the
+    grid.  blk is [1, 4, BE]."""
     r = pl.program_id(0)
     b = pl.program_id(1)
+    row = pl.ds(r % ROWS, 1)
 
     @pl.when(b == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        out_ref[row, :] = jnp.zeros((1, 1), jnp.int32)
 
     @pl.when(b < nblk_ref[r])
     def _acc():
-        px = pts_ref[:, 0:1]                  # [1, 1]
-        py = pts_ref[:, 1:2]
+        px = pts_ref[row, 0:1]                # [1, 1]
+        py = pts_ref[row, 1:2]
         x1 = blk_ref[0, 0:1, :]               # [1, BE]
         y1 = blk_ref[0, 1:2, :]
         x2 = blk_ref[0, 2:3, :]
@@ -143,8 +160,8 @@ def _gather_pip_kernel(first_ref, nblk_ref, pts_ref, blk_ref, out_ref):
         lhs = (px - x1) * (y2 - y1)
         rhs = (py - y1) * (x2 - x1)
         cross = straddle & ((lhs < rhs) == (y2 > y1))
-        out_ref[...] += jnp.sum(cross.astype(jnp.int32), axis=1,
-                                keepdims=True)
+        out_ref[row, :] += jnp.sum(cross.astype(jnp.int32), axis=1,
+                                   keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("max_blocks", "interpret"))
@@ -155,10 +172,12 @@ def crossings_candidates(first: jnp.ndarray, nblk: jnp.ndarray,
     """Crossing counts of [R, 2] points vs their own pool edge slices.
 
     ``first``/``nblk`` [R] i32 are per-point block ranges (already resolved
-    from candidate ids by ops.py; nblk == 0 means no candidate).  Returns
-    [R] i32.
+    from candidate ids by ops.py; nblk == 0 means no candidate).  R must
+    be a multiple of ``ROWS`` and at most ``MAX_ROWS`` (ops.py pads and
+    splits).  Returns [R] i32.
     """
     r = points.shape[0]
+    assert r % ROWS == 0 and r <= MAX_ROWS, r
     nb = blocks.shape[0]
 
     def blk_map(i, b, first_ref, nblk_ref):
@@ -172,10 +191,10 @@ def crossings_candidates(first: jnp.ndarray, nblk: jnp.ndarray,
         num_scalar_prefetch=2,
         grid=(r, max_blocks),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda i, b, *_: (i, 0)),
+            pl.BlockSpec((ROWS, 2), lambda i, b, *_: (i // ROWS, 0)),
             pl.BlockSpec((1, 4, blocks.shape[2]), blk_map),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, b, *_: (i, 0)),
+        out_specs=pl.BlockSpec((ROWS, 1), lambda i, b, *_: (i // ROWS, 0)),
     )
     out = pl.pallas_call(
         _gather_pip_kernel,

@@ -11,6 +11,7 @@ Backend selection (``REPRO_KERNELS`` env var or explicit ``backend=``):
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import NamedTuple, Optional
 
@@ -104,10 +105,40 @@ def pip_candidates(points: jnp.ndarray, pids: jnp.ndarray, pool: EdgePool,
         cross = ref.crossings_candidates(points, first, nblk, pool.blocks,
                                          pool.max_blocks)
     else:
-        cross = gather_pip_kernels.crossings_candidates(
-            first, nblk, points.astype(jnp.float32), pool.blocks,
-            max_blocks=pool.max_blocks, interpret=(b == "interpret"))
+        cross = _crossings_split(first, nblk, points.astype(jnp.float32),
+                                 pool.blocks, max_blocks=pool.max_blocks,
+                                 interpret=(b == "interpret"))
     return (cross & 1).astype(jnp.bool_) & valid
+
+
+@functools.partial(jax.jit, static_argnames=("max_blocks", "interpret"))
+def _crossings_split(first, nblk, points, blocks, *, max_blocks: int,
+                     interpret: bool):
+    """The gather-PIP kernel over any number of rows: padded to its
+    sublane block (pad rows have no candidate) and, past
+    ``gather_pip.MAX_ROWS``, split into equal calls whose
+    scalar-prefetched tables fit SMEM.  The calls run as one ``lax.map``
+    so the kernel compiles once."""
+    n = points.shape[0]
+    n_calls = -(-n // gather_pip_kernels.MAX_ROWS)
+    rows = gather_pip_kernels.ROWS
+    per = -(-n // (n_calls * rows)) * rows
+    pad = per * n_calls - n
+    points = jnp.pad(points, ((0, pad), (0, 0)), constant_values=FAR)
+    first = jnp.pad(first, (0, pad))
+    nblk = jnp.pad(nblk, (0, pad))
+
+    def call(args):
+        f, c, p = args
+        return gather_pip_kernels.crossings_candidates(
+            f, c, p, blocks, max_blocks=max_blocks, interpret=interpret)
+
+    if n_calls == 1:
+        return call((first, nblk, points))[:n]
+    cross = jax.lax.map(call, (first.reshape(n_calls, per),
+                               nblk.reshape(n_calls, per),
+                               points.reshape(n_calls, per, 2)))
+    return cross.reshape(-1)[:n]
 
 
 def assign_cascade(points: jnp.ndarray, quant: jnp.ndarray,
@@ -261,7 +292,7 @@ def segment_reduce(ids: jnp.ndarray, values: Optional[jnp.ndarray] = None,
         # a scratch block that the final slice drops.
         s_pad = ((n_segments + 1 + bs - 1) // bs) * bs
         out = segment_kernels.segment_reduce_sorted(
-            ids_s.reshape(-1, bp), vals_s.reshape(-1, bp), s_pad,
+            ids_s.reshape(-1, bp, 1), vals_s.reshape(-1, bp, 1), s_pad,
             bp=bp, bs=bs, interpret=(b == "interpret"))
         out = tuple(o[:n_segments] for o in out)
     count, total, vmin, vmax = out

@@ -13,7 +13,7 @@ crosses back to host.
 Layout: the caller (``ops.segment_reduce``) stable-sorts rows by block
 id, pads rows to a ``bp`` multiple (pad id = the park segment, sliced
 off afterwards) and segments to a ``bs`` multiple, then hands the
-kernel row tiles of shape [1, bp].  Grid is (segment tiles ×
+kernel row tiles as [bp, 1] columns.  Grid is (segment tiles ×
 row tiles): each step matches its row tile against its segment tile
 with a broadcast-compare one-hot ([bp, bs] in VMEM, a pure VPU
 reduction — counts/sums/extrema all reduce over the row axis), and
@@ -65,17 +65,19 @@ def _segment_kernel(ids_ref, val_ref, cnt_ref, sum_ref, min_ref, max_ref,
         min_ref[...] = jnp.full_like(min_ref, _INF)
         max_ref[...] = jnp.full_like(max_ref, -_INF)
 
-    local = ids_ref[0, :] - j * bs                       # [bp] i32
+    local = ids_ref[0] - j * bs                          # [bp, 1] i32
     bp = local.shape[0]
     iota = jax.lax.broadcasted_iota(jnp.int32, (bp, bs), 1)
-    onehot = local[:, None] == iota                      # [bp, bs] bool
-    v = val_ref[0, :][:, None]                           # [bp, 1] f32
-    cnt_ref[0, :] += jnp.sum(onehot.astype(jnp.int32), axis=0)
-    sum_ref[0, :] += jnp.sum(jnp.where(onehot, v, 0.0), axis=0)
-    min_ref[0, :] = jnp.minimum(
-        min_ref[0, :], jnp.min(jnp.where(onehot, v, _INF), axis=0))
-    max_ref[0, :] = jnp.maximum(
-        max_ref[0, :], jnp.max(jnp.where(onehot, v, -_INF), axis=0))
+    onehot = local == iota                               # [bp, bs] bool
+    v = val_ref[0]                                       # [bp, 1] f32
+    cnt_ref[0] += jnp.sum(onehot.astype(jnp.int32), axis=0, keepdims=True)
+    sum_ref[0] += jnp.sum(jnp.where(onehot, v, 0.0), axis=0, keepdims=True)
+    min_ref[0] = jnp.minimum(
+        min_ref[0],
+        jnp.min(jnp.where(onehot, v, _INF), axis=0, keepdims=True))
+    max_ref[0] = jnp.maximum(
+        max_ref[0],
+        jnp.max(jnp.where(onehot, v, -_INF), axis=0, keepdims=True))
 
 
 @functools.partial(jax.jit,
@@ -87,10 +89,11 @@ def segment_reduce_sorted(ids: jnp.ndarray, values: jnp.ndarray,
     rows.
 
     Args:
-      ids:    [T, bp] i32 — sorted block ids, rows padded with an
-              out-of-range park id (>= ceil-padded segment count is
-              fine: parked rows match no segment tile).
-      values: [T, bp] f32 — value column aligned with ``ids`` (zeros
+      ids:    [T, bp, 1] i32 — sorted block ids as row-tile columns
+              (rows on sublanes), padded with an out-of-range park id
+              (>= ceil-padded segment count is fine: parked rows match
+              no segment tile).
+      values: [T, bp, 1] f32 — value column aligned with ``ids`` (zeros
               when the caller only wants counts).
       n_segments: padded segment count (``bs`` multiple).
     Returns:
@@ -101,9 +104,12 @@ def segment_reduce_sorted(ids: jnp.ndarray, values: jnp.ndarray,
     assert ids.shape == values.shape, (ids.shape, values.shape)
     assert n_segments % bs == 0, (n_segments, bs)
     grid = (n_segments // bs, t)
-    row_spec = pl.BlockSpec((1, ids.shape[1]), lambda j, i: (i, 0))
-    out_spec = pl.BlockSpec((1, bs), lambda j, i: (j, 0))
-    shape = (n_segments // bs, bs)
+    # Tiles are 3-D so that their last two block dims are whole array
+    # dims or (8, 128) multiples, as Mosaic requires: a [1, bp] row
+    # block of a [T, bp] array is refused.
+    row_spec = pl.BlockSpec((1, ids.shape[1], 1), lambda j, i: (i, 0, 0))
+    out_spec = pl.BlockSpec((1, 1, bs), lambda j, i: (j, 0, 0))
+    shape = (n_segments // bs, 1, bs)
     cnt, tot, vmin, vmax = pl.pallas_call(
         functools.partial(_segment_kernel, bs=bs),
         grid=grid,

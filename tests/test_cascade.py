@@ -210,7 +210,7 @@ def test_tuning_roundtrip_and_planner(engines, synth_small, tmp_path):
     iset = GeoIndexSet(census=synth_small.census,
                        covering=engines["fast"].covering, max_level=8)
     tuning = {"winner": "fast_onepass", "be": 128,
-              "device_kind": jax.default_backend(),
+              "device_kind": jax.devices()[0].device_kind,
               "pts_per_sec": 1.5e6, "roofline_fraction": 0.25,
               "recorded": "2026-08-08T00:00:00"}
     iset.record_tuning(tuning)
@@ -230,19 +230,44 @@ def test_tuning_roundtrip_and_planner(engines, synth_small, tmp_path):
     assert eng.fast_index.edge_pool.be == 128
 
 
+def test_roofline_peaks_keyed_by_device_kind():
+    """Roofline shares use published peaks of the chip JAX reports; a
+    device kind with no published peaks is an error, never a default."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "roofline.py")
+    spec = importlib.util.spec_from_file_location("_roofline", path)
+    roofline = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(roofline)
+    assert roofline.device_peaks("TPU v5 lite") == (197e12, 819e9)
+    for kind in ("cpu", "tpu", "TPU v4"):
+        with pytest.raises(ValueError, match="no published peaks"):
+            roofline.device_peaks(kind)
+
+
 def test_planner_ignores_foreign_device_tuning():
-    """A winner recorded on another device kind must not transfer."""
+    """A winner recorded on another device kind must not transfer, and
+    on compiled Pallas kernels (a TPU's backend) a recorded one-pass win
+    is never followed: that kernel does not compile for the TPU."""
     caps = {"census": True, "covering": True, "fast": True,
             "fast_pool": True, "simple": False, "simple_pool": False,
             "sharded": []}
-    tune = {"winner": "fast_onepass", "be": 256, "device_kind": "tpu"}
+    tune = {"winner": "fast_onepass", "be": 256, "device_kind": "cpu"}
     here = plan_for(EngineConfig(), capabilities=caps, tuning=tune,
-                    device_kind="cpu")
+                    platform="tpu", device_kind="TPU v5 lite")
     assert here.strategy != "fast_onepass"
-    there = plan_for(EngineConfig(), capabilities=caps, tuning=tune,
-                     device_kind="tpu")
+    there = plan_for(EngineConfig(backend="ref"), capabilities=caps,
+                     tuning=tune, platform="cpu", device_kind="cpu")
     assert there.strategy == "fast_onepass"
     assert there.fused == "onepass"
+    assert there.device_kind == "cpu" and there.platform == "cpu"
+    chip = dict(tune, device_kind="TPU v5 lite")
+    on_chip = plan_for(EngineConfig(backend="pallas"), capabilities=caps,
+                       tuning=chip, platform="tpu",
+                       device_kind="TPU v5 lite")
+    assert on_chip.strategy == "fast" and on_chip.fused is True
+    assert any("does not compile for the TPU" in r
+               for r in on_chip.reasons)
 
 
 def test_load_accepts_v1_manifest(engines, synth_small, tmp_path):

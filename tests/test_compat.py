@@ -1,10 +1,9 @@
-"""The jax version-compat layer (repro/compat.py, DESIGN.md §12):
+"""The mesh / shard_map surface (repro/compat.py, DESIGN.md §12):
 ambient-mesh tracking, shard_act edge cases as direct unit tests (these
 previously had coverage only through full-model smokes), and a
 multi-device regression test that the activation constraint is actually
-applied inside ``use_mesh(...)`` scopes — on jax 0.4.x it used to no-op
-silently because the bare ``Mesh`` was never recorded anywhere
-``shard_act`` could see.
+applied inside ``use_mesh(...)`` scopes and inside raw ``jax.set_mesh``
+scopes that bypassed it.
 """
 import jax
 import jax.numpy as jnp
@@ -130,12 +129,13 @@ with use_mesh(mesh):
 # memory_kind that fails strict equivalence on some jax versions).
 assert y.sharding.shard_shape(y.shape) == (4, 16), ("use_mesh", y.sharding)
 
-# Raw `with Mesh(...):` scopes (never went through use_mesh) fall back to
-# the resource-env mesh.
-with mesh:
+# Raw `jax.set_mesh(...)` scopes (never went through use_mesh) resolve
+# through jax's own abstract context mesh: the bare PartitionSpec path.
+with jax.set_mesh(mesh):
+    assert get_abstract_mesh().axis_names == ("data", "model")
     z = jax.jit(lambda a: shard_act(a, BATCH, None, None))(
         jnp.ones((8, 4, 16)))
-assert z.sharding.shard_shape(z.shape) == (4, 4, 16), ("mesh-cm", z.sharding)
+assert z.sharding.shard_shape(z.shape) == (4, 4, 16), ("set_mesh", z.sharding)
 
 # Non-divisible batch (7 rows on the 2-way data axis) replicates instead
 # of crashing.
@@ -151,8 +151,9 @@ print("AMBIENT_MESH_OK")
 
 
 def test_compat_shard_map_resolves_ambient_mesh_and_vma_kwarg():
-    """compat.shard_map runs with the new-jax kwarg surface (mesh=None ->
-    ambient mesh, check_vma) on whatever jax is installed."""
+    """compat.shard_map resolves mesh=None from the ambient mesh or a raw
+    jax.set_mesh scope, forwards check_vma, and is a loud ValueError
+    outside every mesh scope."""
     code = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -174,14 +175,17 @@ with use_mesh(mesh):
                       check_vma=True)(x)
 assert float(total) == 128.0, float(total)
 
+with jax.set_mesh(mesh):
+    total = shard_map(body, in_specs=PS("data", None), out_specs=PS(),
+                      check_vma=False)(x)
+assert float(total) == 128.0, float(total)
+
 try:
     shard_map(body, in_specs=PS("data", None), out_specs=PS())
     raised = False
 except ValueError:
     raised = True
-import jax as _j
-if not hasattr(_j, "shard_map"):   # old jax: no ambient mesh -> loud error
-    assert raised
+assert raised
 print("COMPAT_SHARD_MAP_OK")
 """
     assert_subprocess_ok(code, "COMPAT_SHARD_MAP_OK")

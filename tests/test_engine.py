@@ -7,8 +7,10 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import fast as fast_mod
+from repro.core.distributed import STACKED, place_sharded, shard_covering
 from repro.core.engine import EngineConfig, GeoEngine
 from repro.launch.mesh import make_test_mesh
 
@@ -191,6 +193,31 @@ def test_fused_sharded_matches_ground_truth(engines, synth_small,
                           covering=engines["fast"].covering)
     res = eng.assign_sharded(jnp.asarray(xy), make_test_mesh((1, 1)))
     np.testing.assert_array_equal(np.asarray(res.block), bid)
+
+
+def test_assign_sharded_places_index_on_its_mesh(engines, synth_small,
+                                                 points_small):
+    """assign_sharded lays the sharded index out on its mesh: the stacked
+    tables split over "model", everything else (edge pool included)
+    replicated.  The placed index is the cached one, so a second call on
+    the same mesh moves nothing."""
+    xy, *_ = points_small
+    pts = jnp.asarray(xy)
+    fused_cfg = dataclasses.replace(EXACT_CFG, fused=True)
+    eng = GeoEngine.build(synth_small.census, "fast", fused_cfg,
+                          covering=engines["fast"].covering)
+    mesh = make_test_mesh((1, 1))
+    eng.assign_sharded(pts, mesh)
+    sidx = eng.indices.sharded[1]
+    for name in STACKED:
+        assert getattr(sidx, name).sharding == NamedSharding(mesh, P("model"))
+    for arr in (sidx.range_lo, sidx.block_edges, sidx.edge_pool.blocks):
+        assert arr.sharding == NamedSharding(mesh, P())
+    eng.assign_sharded(pts, mesh)
+    assert eng.indices.sharded[1].cell_lo is sidx.cell_lo
+    four = shard_covering(engines["fast"].covering, synth_small.census, 4)
+    with pytest.raises(ValueError, match="cannot be placed"):
+        place_sharded(four, mesh)
 
 
 def test_fused_without_pool_raises_at_construction(engines):

@@ -48,8 +48,8 @@ def test_plan_picks_hybrid_on_heavy_boundary_and_fast_on_light():
                             val=np.array([1, -1]))
     heavy = SimpleNamespace(lo=np.array([0, 4]), hi=np.array([3, 7]),
                             val=np.array([1, -1]))
-    p_light = plan_for(EngineConfig(), covering=light, device_kind="cpu")
-    p_heavy = plan_for(EngineConfig(), covering=heavy, device_kind="cpu")
+    p_light = plan_for(EngineConfig(), covering=light, platform="cpu")
+    p_heavy = plan_for(EngineConfig(), covering=heavy, platform="cpu")
     assert p_light.strategy == "fast"
     assert p_heavy.strategy == "hybrid"
     assert p_heavy.boundary_fraction >= HYBRID_BOUNDARY_FRAC
@@ -60,9 +60,9 @@ def test_plan_fuses_on_tpu_not_cpu():
     cov = SimpleNamespace(lo=np.array([0, 64]), hi=np.array([63, 64]),
                           val=np.array([1, -1]))
     assert plan_for(EngineConfig(), covering=cov,
-                    device_kind="tpu").fused
+                    platform="tpu").fused
     assert not plan_for(EngineConfig(), covering=cov,
-                        device_kind="cpu").fused
+                        platform="cpu").fused
 
 
 def test_plan_respects_capabilities():
@@ -74,7 +74,7 @@ def test_plan_respects_capabilities():
                         "fast": False, "simple_pool": False,
                         "fast_pool": False}
     p = plan_for(EngineConfig(), capabilities=caps_simple_only,
-                 device_kind="tpu")
+                 platform="tpu")
     assert p.strategy == "simple" and p.fused    # pool buildable
     cov = SimpleNamespace(lo=np.array([0, 64]), hi=np.array([63, 64]),
                           val=np.array([1, -1]))
@@ -83,23 +83,23 @@ def test_plan_respects_capabilities():
                     "fast": True, "simple_pool": False,
                     "fast_pool": False}
     p = plan_for(EngineConfig(), covering=cov,
-                 capabilities=caps_no_pool, device_kind="tpu")
+                 capabilities=caps_no_pool, platform="tpu")
     assert p.strategy == "fast" and not p.fused
     assert any("pool" in r or "unusable" in r for r in p.reasons)
     # With the census present the pool is buildable (ensure() attaches
     # it after planning), so a TPU cold start keeps the fused kernel.
     caps_buildable = dict(caps_no_pool, census=True)
     p = plan_for(EngineConfig(), covering=cov,
-                 capabilities=caps_buildable, device_kind="tpu")
+                 capabilities=caps_buildable, platform="tpu")
     assert p.fused
 
 
 def test_plan_recommends_sharding_on_big_batches_only():
     cov = SimpleNamespace(lo=np.array([0, 64]), hi=np.array([63, 64]),
                           val=np.array([1, -1]))
-    big = plan_for(EngineConfig(), covering=cov, device_kind="cpu",
+    big = plan_for(EngineConfig(), covering=cov, platform="cpu",
                    n_points=SHARD_MIN_POINTS, n_devices=4)
-    small = plan_for(EngineConfig(), covering=cov, device_kind="cpu",
+    small = plan_for(EngineConfig(), covering=cov, platform="cpu",
                      n_points=1024, n_devices=4)
     assert big.sharded and big.n_shards == 4
     assert not small.sharded and small.n_shards == 1
