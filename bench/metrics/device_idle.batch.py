@@ -1,0 +1,7 @@
+"""Percent of the batch window in which the device ran no operation
+(profiler trace: 1 - union of device op intervals / window)."""
+from benchlib import readers
+
+
+def read(ctx):
+    return readers.device_idle(ctx)
