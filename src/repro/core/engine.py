@@ -56,10 +56,8 @@ ValueError at *construction*, never at the first assign.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -154,11 +152,6 @@ class GeoEngine:
         self._impl.validate(indices, self.cfg)
         self.plan = plan if plan is not None \
             else plan_mod.explicit_plan(strategy, self.cfg)
-        # Optional observability hook (DESIGN.md §15): when set to a
-        # callable ``f(stage, seconds, batch=b)``, every padded assign is
-        # timed to completion (block_until_ready) and reported.  Off by
-        # default — the hot path must not pay a device sync unasked.
-        self.stage_timer = None
 
     @classmethod
     def build(cls, census: CensusMap, strategy: str = "simple",
@@ -289,23 +282,14 @@ class GeoEngine:
             raise ValueError(f"strategy {self.strategy!r} does not "
                              f"support padded batches")
         b = points.shape[0]
-        timer = self.stage_timer
-        t0 = time.perf_counter() if timer is not None else 0.0
         valid = jnp.arange(b, dtype=jnp.int32) < n_valid
         masked = jnp.where(valid[:, None], points.astype(jnp.float32),
                            jnp.float32(ops.FAR))
         res = self.assign(masked)
         neg = jnp.int32(-1)
-        out = AssignResult(jnp.where(valid, res.state, neg),
-                           jnp.where(valid, res.county, neg),
-                           jnp.where(valid, res.block, neg), res.stats)
-        if timer is not None:
-            # Sync so the reported interval covers the device work, not
-            # just the async dispatch — this is the engine-side truth the
-            # serving layer's host-observed device_assign brackets.
-            jax.block_until_ready(out.block)
-            timer("assign_padded", time.perf_counter() - t0, batch=b)
-        return out
+        return AssignResult(jnp.where(valid, res.state, neg),
+                            jnp.where(valid, res.county, neg),
+                            jnp.where(valid, res.block, neg), res.stats)
 
     # -- index / extent handles (serving layer) ----------------------------
 

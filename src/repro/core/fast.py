@@ -330,13 +330,17 @@ def assign_fast(index: FastIndex, points: jnp.ndarray,
                          "with_pool=True (FastIndex.from_covering)")
     if cfg.fused == "onepass" and cfg.mode == "exact":
         return assign_fast_onepass(index, points, cfg)
-    val = cell_values(index, points)
-    is_boundary = val < 0
-    brow = jnp.clip(-(val + 1), 0, max(index.cand.shape[0] - 1, 0))
-    bid = jnp.where(val >= 0, val, -1)
-    need = is_boundary & (val > OUTSIDE)
+    # Named scopes (geo/locate here, geo/compact and the PIP phases in
+    # resolve.py, geo/parents) are op_name metadata only: they name the
+    # device ops of a profiler trace by phase and change no op.
+    with jax.named_scope("geo/locate"):
+        val = cell_values(index, points)
+        is_boundary = val < 0
+        brow = jnp.clip(-(val + 1), 0, max(index.cand.shape[0] - 1, 0))
+        bid = jnp.where(val >= 0, val, -1)
+        need = is_boundary & (val > OUTSIDE)
+        n_boundary = jnp.sum(need.astype(jnp.int32))
 
-    n_boundary = jnp.sum(need.astype(jnp.int32))
     n_pip = jnp.zeros((), jnp.int32)
     overflow = jnp.zeros((), jnp.int32)
     phase2_miss = jnp.zeros((), jnp.int32)
@@ -364,7 +368,8 @@ def assign_fast(index: FastIndex, points: jnp.ndarray,
             n_pip, overflow = rs.n_pip, rs.overflow
             phase2_miss = rs.phase2_miss
 
-    cid, sid = parents_of(index, bid)
+    with jax.named_scope("geo/parents"):
+        cid, sid = parents_of(index, bid)
     stats = {"n_boundary": n_boundary, "n_pip": n_pip, "overflow": overflow,
              "phase2_miss": phase2_miss}
     return sid, cid, bid, stats

@@ -290,35 +290,41 @@ def _pip_two_phase(points, cand_ids, edges_table, need, backend, cap2,
     capacity overflow, the answer is the fallback, not a drop — but they
     ARE counted in phase2_miss so the degradation is visible)."""
     kk = cand_ids.shape[1]
-    pid0 = cand_ids[:, 0]
-    in0 = _pip_ids(points, pid0, edges_table, edge_pool, backend)
-    in0 = in0 & (pid0 >= 0) & need
-    n_pip = jnp.sum(need.astype(jnp.int32))
-    assign = jnp.where(in0, pid0, -1)
+    with jax.named_scope("geo/pip_phase1"):
+        pid0 = cand_ids[:, 0]
+        in0 = _pip_ids(points, pid0, edges_table, edge_pool, backend)
+        in0 = in0 & (pid0 >= 0) & need
+        n_pip = jnp.sum(need.astype(jnp.int32))
+        assign = jnp.where(in0, pid0, -1)
     if kk == 1:
         return assign, n_pip, jnp.zeros((), jnp.int32)
 
-    miss = need & ~in0
-    n_miss = jnp.sum(miss.astype(jnp.int32))
-    idx2, ok2 = compact_indices(miss, cap2)
-    # Unfilled phase-2 slots alias row 0; guard the counter with ok2 so a
-    # row-0 miss doesn't phantom-count PIP tests for them (it would make
-    # n_pip depend on which row the compaction's buffer order put first).
-    real2 = miss[idx2] & ok2
-    phase2_miss = n_miss - jnp.sum(real2.astype(jnp.int32))
-    rest = cand_ids[idx2, 1:]                        # [R2, K-1]
-    flat_pid = rest.reshape(-1)
-    pts_rep = jnp.repeat(points[idx2], kk - 1, axis=0)
-    in_r = _pip_ids(pts_rep, flat_pid, edges_table, edge_pool, backend)
-    in_r = (in_r & (flat_pid >= 0)).reshape(-1, kk - 1)
-    n_pip = n_pip + jnp.sum((real2[:, None]
-                             & (rest >= 0)).astype(jnp.int32))
-    score = jnp.where(in_r, kk - jnp.arange(1, kk)[None, :], 0)
-    best = jnp.argmax(score, axis=1)
-    hit2 = jnp.any(in_r, axis=1) & miss[idx2] & ok2
-    val2 = jnp.take_along_axis(rest, best[:, None], axis=1)[:, 0]
-    assign = scatter_filled(assign, idx2, ok2,
-                            jnp.where(hit2, val2, assign[idx2]))
+    with jax.named_scope("geo/compact"):
+        miss = need & ~in0
+        n_miss = jnp.sum(miss.astype(jnp.int32))
+        idx2, ok2 = compact_indices(miss, cap2)
+        # Unfilled phase-2 slots alias row 0; guard the counter with ok2
+        # so a row-0 miss doesn't phantom-count PIP tests for them (it
+        # would make n_pip depend on which row the compaction's buffer
+        # order put first).
+        real2 = miss[idx2] & ok2
+        phase2_miss = n_miss - jnp.sum(real2.astype(jnp.int32))
+    with jax.named_scope("geo/phase2_gather"):
+        rest = cand_ids[idx2, 1:]                    # [R2, K-1]
+        flat_pid = rest.reshape(-1)
+        pts_rep = jnp.repeat(points[idx2], kk - 1, axis=0)
+    with jax.named_scope("geo/pip_phase2"):
+        in_r = _pip_ids(pts_rep, flat_pid, edges_table, edge_pool, backend)
+        in_r = (in_r & (flat_pid >= 0)).reshape(-1, kk - 1)
+        n_pip = n_pip + jnp.sum((real2[:, None]
+                                 & (rest >= 0)).astype(jnp.int32))
+        score = jnp.where(in_r, kk - jnp.arange(1, kk)[None, :], 0)
+        best = jnp.argmax(score, axis=1)
+        hit2 = jnp.any(in_r, axis=1) & miss[idx2] & ok2
+        val2 = jnp.take_along_axis(rest, best[:, None], axis=1)[:, 0]
+    with jax.named_scope("geo/compact"):
+        assign = scatter_filled(assign, idx2, ok2,
+                                jnp.where(hit2, val2, assign[idx2]))
     return assign, n_pip, phase2_miss
 
 
@@ -368,13 +374,14 @@ def resolve_candidates(points: jnp.ndarray, cand_ids: Candidates,
     backend = ops.resolve_backend(backend)
     if prior is None:
         prior = jnp.full((n,), -1, jnp.int32)
-    idx, slot_ok = compact_indices(need, cap)
-    sub_pts = points[idx]
-    sub_need = need[idx] & slot_ok
-    sub_cand = cand_ids(idx, sub_pts) if callable(cand_ids) \
-        else cand_ids[idx]
-    if k is not None:
-        sub_cand = sub_cand[:, :k]
+    with jax.named_scope("geo/compact"):
+        idx, slot_ok = compact_indices(need, cap)
+        sub_pts = points[idx]
+        sub_need = need[idx] & slot_ok
+        sub_cand = cand_ids(idx, sub_pts) if callable(cand_ids) \
+            else cand_ids[idx]
+        if k is not None:
+            sub_cand = sub_cand[:, :k]
     if two_phase:
         if cap2 is None:
             cap2 = capacity_for(cap, 0.25, ceiling=cap)
@@ -385,17 +392,18 @@ def resolve_candidates(points: jnp.ndarray, cand_ids: Candidates,
         resolved, n_pip, p2_miss = _pip_sequential(
             sub_pts, sub_cand, edges_table, sub_need, backend,
             edge_pool=edge_pool)
-    if fallback == "first":
-        fb = jnp.where(sub_cand[:, 0] >= 0, sub_cand[:, 0], -1)
-    elif fallback == "prior":
-        fb = prior[idx]
-    else:
-        raise ValueError(f"unknown fallback policy: {fallback!r}")
-    new_val = jnp.where(sub_need,
-                        jnp.where(resolved >= 0, resolved, fb),
-                        prior[idx])
-    assign = scatter_filled(prior, idx, slot_ok, new_val)
-    n_need = jnp.sum(need.astype(jnp.int32))
-    overflow = n_need - jnp.sum(sub_need.astype(jnp.int32))
+    with jax.named_scope("geo/compact"):
+        if fallback == "first":
+            fb = jnp.where(sub_cand[:, 0] >= 0, sub_cand[:, 0], -1)
+        elif fallback == "prior":
+            fb = prior[idx]
+        else:
+            raise ValueError(f"unknown fallback policy: {fallback!r}")
+        new_val = jnp.where(sub_need,
+                            jnp.where(resolved >= 0, resolved, fb),
+                            prior[idx])
+        assign = scatter_filled(prior, idx, slot_ok, new_val)
+        n_need = jnp.sum(need.astype(jnp.int32))
+        overflow = n_need - jnp.sum(sub_need.astype(jnp.int32))
     return assign, ResolveStats(n_need=n_need, n_pip=n_pip,
                                 overflow=overflow, phase2_miss=p2_miss)

@@ -5,11 +5,13 @@ device stage itself needs opening up (which kernel, which fusion, how
 much HBM traffic), the JAX profiler is the right tool.  This module is
 the thin, failure-proof seam between the two:
 
-* ``device_annotation(name)`` — context manager wrapping
-  ``jax.profiler.TraceAnnotation``, so device-stage assigns show up as
-  named ranges in a captured device trace (TensorBoard / Perfetto).
-  ``GeoServer`` applies it around every padded assign when
-  ``ServeConfig.trace_device=True``.
+* ``profile_range(name, **kw)`` — context manager wrapping
+  ``jax.profiler.TraceAnnotation(name, **kw)`` (the keyword args become
+  the range's event stats), so serving stages show up as named host
+  ranges on the same clock as the device ops of a captured trace
+  (TensorBoard / Perfetto).  ``GeoServer`` opens its ``geo/`` stage
+  ranges through ``ranges(cfg.trace_device)``: the real helper when
+  ``ServeConfig.trace_device=True``, a no-op otherwise.
 * ``start_profile(logdir)`` / ``stop_profile()`` — the capture pair
   (``jax.profiler.start_trace``/``stop_trace``), exposed on
   ``GeoServer`` so a load run can bracket its SLO trial with a device
@@ -24,7 +26,7 @@ from __future__ import annotations
 import contextlib
 import threading
 
-__all__ = ["device_annotation", "start_profile", "stop_profile",
+__all__ = ["profile_range", "ranges", "start_profile", "stop_profile",
            "profiler_available"]
 
 _warned = set()
@@ -47,20 +49,41 @@ def profiler_available() -> bool:
         return False
 
 
+def _trace_annotation(name: str, **kw):
+    import jax.profiler
+    return jax.profiler.TraceAnnotation(name, **kw)
+
+
+# What ``profile_range`` opens its ranges with; a test substitutes a
+# recorder here.
+range_factory = _trace_annotation
+
+_NO_RANGE = contextlib.nullcontext()
+
+
 @contextlib.contextmanager
-def device_annotation(name: str):
-    """Named profiler range around a device call; no-op when the
-    profiler is unavailable."""
+def profile_range(name: str, **kw):
+    """Named profiler range (keyword args stored as its event stats);
+    no-op when the profiler is unavailable."""
     try:
-        import jax.profiler
-        ctx = jax.profiler.TraceAnnotation(name)
+        ctx = range_factory(name, **kw)
     except Exception:                      # pragma: no cover - env-specific
         _warn_once("annotation", "jax.profiler.TraceAnnotation "
-                                 "unavailable — device annotations off")
+                                 "unavailable — profiler ranges off")
         yield
         return
     with ctx:
         yield
+
+
+def _no_range(name: str, **kw):
+    return _NO_RANGE
+
+
+def ranges(enabled: bool):
+    """``profile_range`` when ``enabled``, else a no-op of the same
+    signature: the one switch a caller checks, once."""
+    return profile_range if enabled else _no_range
 
 
 def start_profile(logdir: str) -> bool:

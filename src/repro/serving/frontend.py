@@ -332,7 +332,8 @@ class AsyncGeoServer(GeoServer):
                 self._recover_batch(work, exc)
             finally:
                 if any(r.cache is not None for r in self.regions):
-                    self.metrics.observe_cache(self.cache_snapshot())
+                    with self._range("geo/cache_gauges", batch=work.seq):
+                        self.metrics.observe_cache(self.cache_snapshot())
 
     def _recover_batch(self, work, exc: Exception) -> None:
         """The async spelling of the sync server's requeue-on-failure:

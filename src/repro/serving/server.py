@@ -98,12 +98,13 @@ class ServeConfig:
     #                                       SLOs instead of waiting for
     #                                       the size trigger.  None =
     #                                       size/submit-driven only.
-    trace_device: bool = False         # wrap device-stage assigns in
-    #                                    jax.profiler.TraceAnnotation so
-    #                                    a captured device trace
-    #                                    (start_profile/stop_profile)
-    #                                    names each region/bucket range
-    #                                    (DESIGN.md §15).
+    trace_device: bool = False         # open the serve path's geo/
+    #                                    stage ranges (and each padded
+    #                                    assign's region/bucket range) as
+    #                                    jax.profiler.TraceAnnotations, on
+    #                                    the clock of a captured device
+    #                                    trace (start_profile/
+    #                                    stop_profile; DESIGN.md §15).
     analytics: Optional[AnalyticsConfig] = None  # opt-in windowed
     #                                    streaming analytics: every served
     #                                    batch also feeds a per-region
@@ -252,6 +253,8 @@ class _BatchWork:
     cid: np.ndarray
     bid: np.ndarray
     device: list                    # [(region_ix, sel rows, miss rows)]
+    seq: int                        # batch id: the ``batch`` stat of
+    #                                 every profiler range of this batch
     ats: float = 0.0                # analytics event time, stamped in
     #                                 the (ordered) host stage
     src: Optional[np.ndarray] = None  # [n] i64 source id (request seq)
@@ -274,6 +277,8 @@ class GeoServer:
         always on, tracer or not."""
         self.cfg = cfg or ServeConfig()
         self.tracer = tracer
+        self._range = obs_profile.ranges(self.cfg.trace_device)
+        self._batch_seq = itertools.count()
         if isinstance(engines, GeoEngine):
             engines = [engines]
         if not engines:
@@ -511,28 +516,35 @@ class GeoServer:
         *sampled* ticket in the batch gets queue_wait + host_prepare
         spans (children: route, per-region cache_lookup/cache_learn) —
         the whole batch shares one timing, each sampled request records
-        its own copy so per-request timelines stay self-contained."""
+        its own copy so per-request timelines stay self-contained.  With
+        ``trace_device`` the same intervals are open as profiler ranges
+        (``geo/host_prepare`` over ``geo/route``, ``geo/cache_lookup``,
+        ``geo/cache_learn``), each carrying the batch id ``batch``."""
+        seq = next(self._batch_seq)
+        rng = self._range
         tp0 = time.perf_counter()
-        pts = mb.points
-        n = len(pts)
-        owner = self._route(pts)
-        tr1 = time.perf_counter()
-        sid = np.full(n, -1, np.int32)
-        cid = np.full(n, -1, np.int32)
-        bid = np.full(n, -1, np.int32)
-        device = []
-        sub = [("route", tp0, tr1, {})]    # host_prepare sub-intervals
-        for r_ix, region in enumerate(self.regions):
-            sel = np.nonzero(owner == r_ix)[0]
-            if not sel.size:
-                continue
-            rs, rc, rb, mi, rsub = self._host_stage(region, pts[sel],
-                                                    r_ix)
-            sub += rsub
-            sid[sel], cid[sel], bid[sel] = rs, rc, rb
-            if mi.size:
-                device.append((r_ix, sel, mi))
-        tp1 = time.perf_counter()
+        with rng("geo/host_prepare", batch=seq):
+            pts = mb.points
+            n = len(pts)
+            with rng("geo/route", batch=seq):
+                owner = self._route(pts)
+                tr1 = time.perf_counter()
+            sid = np.full(n, -1, np.int32)
+            cid = np.full(n, -1, np.int32)
+            bid = np.full(n, -1, np.int32)
+            device = []
+            sub = [("route", tp0, tr1, {})]    # host_prepare sub-intervals
+            for r_ix, region in enumerate(self.regions):
+                sel = np.nonzero(owner == r_ix)[0]
+                if not sel.size:
+                    continue
+                rs, rc, rb, mi, rsub = self._host_stage(region, pts[sel],
+                                                        r_ix, seq)
+                sub += rsub
+                sid[sel], cid[sel], bid[sel] = rs, rc, rb
+                if mi.size:
+                    device.append((r_ix, sel, mi))
+            tp1 = time.perf_counter()
         self.metrics.observe_stage("host_prepare", tp1 - tp0)
         seen = set()
         for ticket, _, _, _ in mb.parts:
@@ -564,9 +576,10 @@ class GeoServer:
             src = np.empty(n, np.int64)
             for ticket, _, batch_off, length in mb.parts:
                 src[batch_off:batch_off + length] = ticket.seq
-        return _BatchWork(mb, owner, sid, cid, bid, device, ats, src)
+        return _BatchWork(mb, owner, sid, cid, bid, device, seq, ats, src)
 
-    def _host_stage(self, region: _Region, pts: np.ndarray, r_ix: int):
+    def _host_stage(self, region: _Region, pts: np.ndarray, r_ix: int,
+                    seq: int):
         """Cache lookup + learn for one region's slice of a batch;
         returns (state, county, block, miss_rows, sub_intervals) with
         hit rows filled and miss rows -1.  Off-extent points stay
@@ -589,22 +602,23 @@ class GeoServer:
         if region.cache is None:
             return sid, cid, bid, np.nonzero(miss)[0], []
         tl0 = time.perf_counter()
-        codes = np_quantize_codes(region.cache.table.quant,
-                                  region.cache.table.max_level, pts)
-        eligible = np_extent_mask(region.cache.table.quant,
-                                  region.cache.table.max_level, pts)
-        n_hit = 0
-        n_eligible = int(eligible.sum())
-        if n_eligible:
-            el = np.nonzero(eligible)[0]
-            cbid, hit = region.cache.lookup(codes[el])
-            hit_rows = el[hit]
-            n_hit = int(hit_rows.size)
-            bid[hit_rows] = cbid[hit]
-            sid[hit_rows], cid[hit_rows] = \
-                region.host_parents_of(bid[hit_rows])
-            miss[hit_rows] = False
-        tl1 = time.perf_counter()
+        with self._range("geo/cache_lookup", batch=seq, region=r_ix):
+            codes = np_quantize_codes(region.cache.table.quant,
+                                      region.cache.table.max_level, pts)
+            eligible = np_extent_mask(region.cache.table.quant,
+                                      region.cache.table.max_level, pts)
+            n_hit = 0
+            n_eligible = int(eligible.sum())
+            if n_eligible:
+                el = np.nonzero(eligible)[0]
+                cbid, hit = region.cache.lookup(codes[el])
+                hit_rows = el[hit]
+                n_hit = int(hit_rows.size)
+                bid[hit_rows] = cbid[hit]
+                sid[hit_rows], cid[hit_rows] = \
+                    region.host_parents_of(bid[hit_rows])
+                miss[hit_rows] = False
+            tl1 = time.perf_counter()
         self.metrics.inc("cache_hits_total", n_hit)
         self.metrics.inc("cache_misses_total", n_eligible - n_hit)
         sub = [("cache_lookup", tl0, tl1,
@@ -616,10 +630,12 @@ class GeoServer:
             # not the engine — exact by the interior invariant, so
             # learning before the device assign changes nothing but
             # makes the host stage self-contained.
-            inserted = region.cache.learn(codes[learnable])
-            tn1 = time.perf_counter()
+            tn0 = time.perf_counter()
+            with self._range("geo/cache_learn", batch=seq, region=r_ix):
+                inserted = region.cache.learn(codes[learnable])
+                tn1 = time.perf_counter()
             self.metrics.inc("cache_insertions_total", inserted)
-            sub.append(("cache_learn", tl1, tn1,
+            sub.append(("cache_learn", tn0, tn1,
                         {"region": r_ix, "inserted": inserted}))
         return sid, cid, bid, mi, sub
 
@@ -636,98 +652,112 @@ class GeoServer:
         after *every* region of its batch served — each sampled ticket
         records every device interval of the batch.  The completing
         part additionally records the ``merge`` span and closes the
-        request's root."""
-        pts = work.mb.points
-        dev = []                           # (t0, t1, attrs) per region
-        for r_ix, sel, mi in work.device:
-            region = self.regions[r_ix]
-            td0 = time.perf_counter()
-            rs, rc, rb = self._device_stage(region, pts[sel], mi)
-            td1 = time.perf_counter()
-            self.metrics.observe_stage("device_assign", td1 - td0)
-            dev.append((td0, td1,
-                        {"region": r_ix, "rows": int(mi.size),
-                         "bucket": bucket_for(mi.size, self.cfg.buckets)}))
-            work.sid[sel[mi]] = rs
-            work.cid[sel[mi]] = rc
-            work.bid[sel[mi]] = rb
-        self.metrics.inc("batches")
-        self.metrics.inc("points_served", len(pts))
-        if work.src is not None:
-            # Feed the windowed analytics before tickets fill: a synced
-            # submit (or an async drain) then implies this batch's rows
-            # are already folded into the aggregator — the served-vs-
-            # direct equality tests hinge on that ordering.  Cache hits
-            # and device answers feed alike; -1 rows count as off_map.
-            ta0 = time.perf_counter()
-            n_obs = 0
-            for r_ix, region in enumerate(self.regions):
-                if region.analytics is None:
-                    continue
-                sel = work.owner == r_ix
-                if sel.any():
-                    n_obs += region.analytics.observe(
-                        work.ats, work.bid[sel], work.src[sel])
-            self.metrics.inc("analytics_points", n_obs)
-            self.metrics.observe_stage("analytics_observe",
-                                       time.perf_counter() - ta0)
-        if dev:
-            seen = set()
-            for ticket, _, _, _ in work.mb.parts:
-                if ticket.trace is None or id(ticket) in seen:
-                    continue
-                seen.add(id(ticket))
-                attrs = {"attempt": ticket.attempt} if ticket.attempt \
-                    else {}
-                for td0, td1, dattrs in dev:
-                    ticket.trace.span("device_assign", td0, td1,
-                                      **dattrs, **attrs)
-        tm0 = time.perf_counter()
-        for ticket, req_off, batch_off, length in work.mb.parts:
-            bsl = slice(batch_off, batch_off + length)
-            if ticket.fill(req_off, length, work.sid[bsl], work.cid[bsl],
-                           work.bid[bsl], work.owner[bsl]):
-                self.metrics.observe_latency(ticket.latency_s)
-                if ticket.trace is not None:
-                    done = time.perf_counter()
-                    ticket.trace.span("merge", tm0, done)
-                    ticket.trace.end(done, n_points=len(ticket.block))
-        self.metrics.observe_stage("merge", time.perf_counter() - tm0)
+        request's root.  With ``trace_device`` the stage intervals are
+        open as profiler ranges (``geo/complete_batch`` over each
+        region's ``geo/device_stage``, ``geo/analytics_observe`` and
+        ``geo/merge``), under the batch id the host stage gave them."""
+        rng, seq = self._range, work.seq
+        with rng("geo/complete_batch", batch=seq):
+            pts = work.mb.points
+            dev = []                       # (t0, t1, attrs) per region
+            for r_ix, sel, mi in work.device:
+                region = self.regions[r_ix]
+                bucket = bucket_for(mi.size, self.cfg.buckets)
+                td0 = time.perf_counter()
+                with rng("geo/device_stage", batch=seq, region=r_ix,
+                         bucket=bucket):
+                    rs, rc, rb = self._device_stage(region, pts[sel], mi,
+                                                    bucket, seq)
+                    td1 = time.perf_counter()
+                self.metrics.observe_stage("device_assign", td1 - td0)
+                dev.append((td0, td1, {"region": r_ix, "rows": int(mi.size),
+                                       "bucket": bucket}))
+                work.sid[sel[mi]] = rs
+                work.cid[sel[mi]] = rc
+                work.bid[sel[mi]] = rb
+            self.metrics.inc("batches")
+            self.metrics.inc("points_served", len(pts))
+            if work.src is not None:
+                # Feed the windowed analytics before tickets fill: a synced
+                # submit (or an async drain) then implies this batch's rows
+                # are already folded into the aggregator — the served-vs-
+                # direct equality tests hinge on that ordering.  Cache hits
+                # and device answers feed alike; -1 rows count as off_map.
+                ta0 = time.perf_counter()
+                with rng("geo/analytics_observe", batch=seq):
+                    n_obs = 0
+                    for r_ix, region in enumerate(self.regions):
+                        if region.analytics is None:
+                            continue
+                        sel = work.owner == r_ix
+                        if sel.any():
+                            n_obs += region.analytics.observe(
+                                work.ats, work.bid[sel], work.src[sel])
+                    ta1 = time.perf_counter()
+                self.metrics.inc("analytics_points", n_obs)
+                self.metrics.observe_stage("analytics_observe", ta1 - ta0)
+            if dev:
+                seen = set()
+                for ticket, _, _, _ in work.mb.parts:
+                    if ticket.trace is None or id(ticket) in seen:
+                        continue
+                    seen.add(id(ticket))
+                    attrs = {"attempt": ticket.attempt} if ticket.attempt \
+                        else {}
+                    for td0, td1, dattrs in dev:
+                        ticket.trace.span("device_assign", td0, td1,
+                                          **dattrs, **attrs)
+            tm0 = time.perf_counter()
+            with rng("geo/merge", batch=seq):
+                for ticket, req_off, batch_off, length in work.mb.parts:
+                    bsl = slice(batch_off, batch_off + length)
+                    if ticket.fill(req_off, length, work.sid[bsl],
+                                   work.cid[bsl], work.bid[bsl],
+                                   work.owner[bsl]):
+                        self.metrics.observe_latency(ticket.latency_s)
+                        if ticket.trace is not None:
+                            done = time.perf_counter()
+                            ticket.trace.span("merge", tm0, done)
+                            ticket.trace.end(done,
+                                             n_points=len(ticket.block))
+                tm1 = time.perf_counter()
+            self.metrics.observe_stage("merge", tm1 - tm0)
 
     def _device_stage(self, region: _Region, pts: np.ndarray,
-                      mi: np.ndarray):
-        """One region's padded engine assign over its miss rows; returns
-        (state, county, block) [len(mi)] i32.
+                      mi: np.ndarray, bucket: int, seq: int):
+        """One region's padded engine assign over its miss rows, padded
+        to ``bucket``; returns (state, county, block) [len(mi)] i32.
 
         Miss rows keep the engine's own state/county — NOT a re-derivation
         from the block id: the cascade can resolve a point's state yet
         lose it at the county/block level (bbox gap, capacity overflow),
-        and that partial answer must survive serving bit-identically."""
-        bucket = bucket_for(mi.size, self.cfg.buckets)
-        padded = pad_points(pts[mi], bucket)
-        # Slot accounting at the device edge: this is the padding the
-        # engine actually computes, post-cache and post-routing —
-        # batch_fill_ratio measures real ladder waste.
-        self.metrics.inc("padded_slots", bucket)
-        self.metrics.inc("valid_slots", mi.size)
-        if self.cfg.trace_device:
-            # Named profiler range so a captured device trace
-            # (start_profile/stop_profile) attributes kernels to the
-            # serving stage that launched them (DESIGN.md §15).
-            with obs_profile.device_annotation(
-                    f"geo_device_assign/b{bucket}"):
+        and that partial answer must survive serving bit-identically.
+
+        Profiler ranges (``trace_device``): ``geo/dispatch`` covers the
+        host's padding and the asynchronous dispatch, ``geo/pull`` the
+        wait for the device and the id copies, ``geo/stats_fold`` the
+        counter folds."""
+        rng = self._range
+        with rng("geo/dispatch", batch=seq):
+            padded = pad_points(pts[mi], bucket)
+            # Slot accounting at the device edge: this is the padding the
+            # engine actually computes, post-cache and post-routing —
+            # batch_fill_ratio measures real ladder waste.
+            self.metrics.inc("padded_slots", bucket)
+            self.metrics.inc("valid_slots", mi.size)
+            with rng(f"geo_device_assign/b{bucket}"):
                 res = region.engine.assign_padded(jnp.asarray(padded),
                                                   mi.size)
-        else:
-            res = region.engine.assign_padded(jnp.asarray(padded),
-                                              mi.size)
-        with region.lock:
-            region.stats = res.stats if region.stats is None \
-                else region.stats.merge(res.stats)
-        self.metrics.observe_geo(res.stats)
-        return (np.asarray(res.state)[:mi.size],
-                np.asarray(res.county)[:mi.size],
-                np.asarray(res.block)[:mi.size])
+        with rng("geo/pull", batch=seq):
+            out = (np.asarray(res.state)[:mi.size],
+                   np.asarray(res.county)[:mi.size],
+                   np.asarray(res.block)[:mi.size])
+        with rng("geo/stats_fold", batch=seq):
+            with region.lock:
+                region.stats = res.stats if region.stats is None \
+                    else region.stats.merge(res.stats)
+            self.metrics.observe_geo(res.stats)
+        return out
 
     # -- introspection -----------------------------------------------------
 
@@ -794,8 +824,9 @@ class GeoServer:
     def start_profile(self, logdir: str) -> bool:
         """Begin a JAX device-trace capture into ``logdir`` (True if it
         started); pair with ``stop_profile``.  With
-        ``ServeConfig(trace_device=True)`` each padded assign shows up
-        as a named range in the capture."""
+        ``ServeConfig(trace_device=True)`` the serve path's ``geo/``
+        stages and each padded assign show up as named ranges in the
+        capture."""
         return obs_profile.start_profile(logdir)
 
     def stop_profile(self) -> bool:

@@ -21,7 +21,7 @@ import pytest
 
 from repro.core.engine import EngineConfig, GeoEngine
 from repro.obs import (LatencyHistogram, SpanBuffer, Tracer,
-                       device_annotation, profiler_available)
+                       profile_range, profiler_available)
 from repro.obs.trace import Span
 from repro.serving import (AsyncGeoServer, FrontendConfig, GeoServer,
                            ServeConfig)
@@ -474,10 +474,10 @@ def test_shed_request_closes_trace_without_orphans(engine, points_small):
     assert sheds[0].attrs["error"] == "QueueFull"
 
 
-# -- profiler hooks + engine stage timer -------------------------------------
+# -- profiler hooks -----------------------------------------------------------
 
 def test_device_annotation_is_exception_safe():
-    with device_annotation("geo_test/b256"):
+    with profile_range("geo_test/b256", batch=3):
         x = 1 + 1
     assert x == 2
     assert isinstance(profiler_available(), bool)
@@ -492,21 +492,158 @@ def test_trace_device_config_serves_identically(engine, points_small):
     np.testing.assert_array_equal(res.block, direct)
 
 
-def test_engine_stage_timer_hook(engine, points_small):
+# -- profiler ranges on the served path and scopes in the core ---------------
+
+class _RangeRecorder:
+    """Stands in for ``obs.profile.range_factory``: records each range as
+    (name, thread, start, end, kwargs) when it closes."""
+
+    def __init__(self):
+        self.ranges = []
+        self._lock = threading.Lock()
+
+    def __call__(self, name, **kw):
+        rec = self
+
+        class _Range:
+            def __enter__(self):
+                self.t0 = time.perf_counter()
+
+            def __exit__(self, *exc):
+                t1 = time.perf_counter()
+                with rec._lock:
+                    rec.ranges.append((name, threading.get_ident(),
+                                       self.t0, t1, kw))
+        return _Range()
+
+
+@pytest.fixture(scope="module")
+def served_ranges(engine, points_small):
+    """The same requests through an AsyncGeoServer (cache and analytics
+    on) with ``trace_device`` on, then off, under one recorder: the
+    ranges each run opened and its answers."""
+    from repro.analytics import AnalyticsConfig
+    from repro.obs import profile as obs_profile
     xy, *_ = points_small
-    calls = []
-    engine.stage_timer = lambda stage, s, **kw: calls.append(
-        (stage, s, kw))
-    try:
-        engine.assign_padded(jnp.asarray(np.zeros((64, 2), np.float32)),
-                             10)
-    finally:
-        engine.stage_timer = None
-    assert len(calls) == 1
-    stage, seconds, kw = calls[0]
-    assert stage == "assign_padded"
-    assert seconds > 0
-    assert kw == {"batch": 64}
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        for on in (True, False):
+            rec = _RangeRecorder()
+            mp.setattr(obs_profile, "range_factory", rec)
+            cfg = ServeConfig(buckets=BUCKETS, cache=True, trace_device=on,
+                              analytics=AnalyticsConfig(60.0, 10.0))
+            with AsyncGeoServer(engine, cfg) as server:
+                futs, off = [], 0
+                for size in STREAM:
+                    futs.append(server.submit_async(xy[off:off + size]))
+                    off += size
+                answers = [f.result(timeout=30) for f in futs]
+                server.drain(timeout=30)
+            out[on] = (rec.ranges, answers)
+    return out
+
+
+def _contains(outer, inner):
+    return outer[1] == inner[1] and outer[2] <= inner[2] \
+        and inner[3] <= outer[3]
+
+
+@pytest.mark.timeout(60)
+def test_profile_ranges_nest_on_each_thread(served_ranges):
+    ranges, _ = served_ranges[True]
+    assert ranges
+    by_thread = defaultdict(list)
+    for r in ranges:
+        by_thread[r[1]].append(r)
+    for rs in by_thread.values():
+        stack = []
+        for r in sorted(rs, key=lambda r: (r[2], -r[3])):
+            while stack and stack[-1][3] <= r[2]:
+                stack.pop()
+            assert not stack or r[3] <= stack[-1][3], (stack[-1], r)
+            stack.append(r)
+
+
+@pytest.mark.timeout(60)
+def test_profile_ranges_of_a_batch_share_its_id_across_threads(
+        served_ranges):
+    ranges, _ = served_ranges[True]
+    host = [r for r in ranges if r[0] == "geo/host_prepare"]
+    done = [r for r in ranges if r[0] == "geo/complete_batch"]
+    batches = sorted(r[4]["batch"] for r in host)
+    assert batches and batches == sorted(r[4]["batch"] for r in done)
+    assert len(set(batches)) == len(batches)
+    flusher = {r[1] for r in host}
+    replica = {r[1] for r in done}
+    assert len(flusher) == 1 and len(replica) == 1 and flusher != replica
+    for r in ranges:
+        if not r[0].startswith("geo/"):
+            continue
+        assert r[4]["batch"] in batches, r
+        if r[0] == "geo/cache_gauges":       # after its batch, on the replica
+            assert r[1] in replica
+            continue
+        outer = host if r[1] in flusher else done
+        owner = [o for o in outer if _contains(o, r)]
+        assert len(owner) == 1 and owner[0][4]["batch"] == r[4]["batch"], r
+    names = {r[0] for r in ranges}
+    assert {"geo/route", "geo/cache_lookup", "geo/cache_learn",
+            "geo/dispatch", "geo/analytics_observe", "geo/merge",
+            "geo/cache_gauges"} <= names
+
+
+@pytest.mark.timeout(60)
+def test_device_stage_range_holds_dispatch_pull_and_stats_fold(
+        served_ranges):
+    ranges, _ = served_ranges[True]
+    stages = [r for r in ranges if r[0] == "geo/device_stage"]
+    assert stages
+    for st in stages:
+        inside = [r for r in ranges if _contains(st, r) and r is not st]
+        kids = {r[0] for r in inside if r[4].get("batch") == st[4]["batch"]}
+        assert {"geo/dispatch", "geo/pull", "geo/stats_fold"} <= kids
+        assert any(r[0] == f"geo_device_assign/b{st[4]['bucket']}"
+                   and any(_contains(d, r) for d in inside
+                           if d[0] == "geo/dispatch") for r in inside)
+
+
+@pytest.mark.timeout(60)
+def test_no_profile_range_opens_with_trace_device_off(served_ranges):
+    ranges, _ = served_ranges[False]
+    assert ranges == []
+
+
+@pytest.mark.timeout(60)
+def test_answers_are_identical_with_profile_ranges_on_and_off(
+        served_ranges):
+    (_, on), (_, off) = served_ranges[True], served_ranges[False]
+    assert len(on) == len(off) == len(STREAM)
+    for a, b in zip(on, off):
+        for f in ("state", "county", "block", "region"):
+            np.testing.assert_array_equal(getattr(a, f), getattr(b, f))
+
+
+SCOPES = ("geo/locate", "geo/compact", "geo/pip_phase1", "geo/phase2_gather",
+          "geo/pip_phase2", "geo/parents")
+
+
+@pytest.fixture(scope="module")
+def assign_fast_op_names(engine, points_small):
+    """The op_name metadata of the exact fast assign, compiled."""
+    import re
+
+    from repro.core import fast
+    xy, *_ = points_small
+    text = fast.assign_fast.lower(
+        engine.indices.fast, jnp.asarray(xy[:256]),
+        cfg=engine.cfg.fast_cfg()).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_assign_fast_names_each_phase_by_its_scope(assign_fast_op_names,
+                                                   scope):
+    assert any(f"/{scope}/" in f"/{n}/" for n in assign_fast_op_names)
 
 
 # -- the exported-trace validator itself -------------------------------------
