@@ -310,7 +310,10 @@ def _pip_two_phase(points, cand_ids, edges_table, need, backend, cap2,
         real2 = miss[idx2] & ok2
         phase2_miss = n_miss - jnp.sum(real2.astype(jnp.int32))
     with jax.named_scope("geo/phase2_gather"):
-        rest = cand_ids[idx2, 1:]                    # [R2, K-1]
+        # Whole rows by a 1-D index, then the static column slice: a row
+        # index with a column offset in one gather ([idx2, 1:]) is
+        # expanded by the TPU compiler into a loop of one row per trip.
+        rest = cand_ids[idx2][:, 1:]                 # [R2, K-1]
         flat_pid = rest.reshape(-1)
         pts_rep = jnp.repeat(points[idx2], kk - 1, axis=0)
     with jax.named_scope("geo/pip_phase2"):

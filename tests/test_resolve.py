@@ -69,10 +69,21 @@ def test_parity_with_host_oracle(poly_world):
         assert int(stats.n_need) == n
 
 
-def test_two_phase_matches_sequential(poly_world):
+def shuffled_cands(n, n_poly, k, seed=0):
+    """[n, k] candidate rows holding each polygon id once, each row in its
+    own random slot order, -1 in the other k - n_poly slots."""
+    rng = np.random.default_rng(seed)
+    row = np.full(k, -1, np.int32)
+    row[:n_poly] = np.arange(n_poly)
+    return jnp.asarray(np.stack([rng.permutation(row) for _ in range(n)]))
+
+
+@pytest.mark.parametrize("k", [4, 8])
+def test_two_phase_matches_sequential(poly_world, k):
     rings, edges, pts = poly_world
     n = len(pts)
-    cand = all_cands(n, len(rings))
+    cand = all_cands(n, len(rings)) if k == len(rings) \
+        else shuffled_cands(n, len(rings), k)
     need = jnp.asarray(np.arange(n) % 3 != 0)     # a non-trivial subset
     seq, _ = resolve_candidates(jnp.asarray(pts), cand, edges, need,
                                 cap=n, backend="ref", two_phase=False)
@@ -80,6 +91,11 @@ def test_two_phase_matches_sequential(poly_world):
                                 cap=n, backend="ref", two_phase=True,
                                 cap2=n)
     np.testing.assert_array_equal(np.asarray(seq), np.asarray(two))
+    # Phase 2 decides: matches land in every slot past slot 0.
+    seq, c = np.asarray(seq), np.asarray(cand)
+    hit = (seq >= 0) & np.asarray(need)
+    slots = np.argmax(c[hit] == seq[hit, None], axis=1)
+    assert set(range(1, k)) <= set(slots.tolist())
 
 
 def test_overflow_accounting_exact(poly_world):

@@ -9,6 +9,7 @@ one process at a time may load the TPU library, and with several test
 workers only the worker given this file may try.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import fast
 from repro.core.artifact import GeoIndexSet
 from repro.core.engine import EngineConfig
 from repro.core.registry import get_strategy
@@ -102,6 +104,37 @@ def test_bbox_count_select_compiles(one_chip):
 def test_bbox_mask_compiles(one_chip):
     _compile(bbox.bbox_mask, one_chip, ((N_BIG, 2), F32),
              ((4, bbox.DEF_BM), F32))
+
+
+_GATHER_LOOP = re.compile(r' while\(.*op_name="[^"]*/gather"')
+
+
+@pytest.mark.timeout(300)
+def test_fast_assign_gathers_without_loops(one_chip, synth_small):
+    """The exact fused fast assign at the serving bucket compiles with no
+    gather expanded into a loop: the TPU compiler turns a gather it cannot
+    lower natively into a ``while`` of one slice per trip, which ran 0.6 s
+    of each 1,048,576-point call on a v5e.  The metadata rides in the
+    compile key, as the benchmark's scope reader compiles it, so the
+    op_names are this build's own."""
+    index = GeoIndexSet.build(synth_small.census, ("fast",), ("fast",),
+                              max_level=6).fast
+    assert index.cand.shape[1] == 8
+    spec = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        index)
+    x = jax.ShapeDtypeStruct((N_SMALL, 2), F32, sharding=one_chip)
+    cfg = fast.FastConfig(mode="exact", cap_boundary=1.0, backend="pallas",
+                          fused=True)
+    key = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, key)
+    jax.config.update(key, True)
+    try:
+        text = fast.assign_fast.lower(spec, x, cfg=cfg).compile().as_text()
+    finally:
+        jax.config.update(key, before)
+    assert "tpu_custom_call" in text
+    assert [ln for ln in text.splitlines() if _GATHER_LOOP.search(ln)] == []
 
 
 @pytest.mark.parametrize("strategy,fused", [("fast_onepass", False),
