@@ -297,6 +297,10 @@ class GeoIndexSet:
             # Autotune record (schema v2): round-trips verbatim so a
             # reloaded artifact plans from recorded measurements.
             "tuning": self.tuning,
+            # The covering build's per-level counters (informational;
+            # absent from artifacts saved before it was recorded).
+            "covering_build": ([] if self.covering is None
+                               else self.covering.build_stats),
         }
         np.savez_compressed(os.path.join(path, ARRAYS_NAME), **arrays)
         with open(os.path.join(path, MANIFEST_NAME), "w") as f:
@@ -340,7 +344,8 @@ class GeoIndexSet:
                 **{f: arrays[f"covering_{f}"] for f in _COVER_FIELDS},
                 max_level=int(manifest["max_level"]), extent=extent,
                 n_interior=int((val >= 0).sum()),
-                n_boundary=int((val < 0).sum()))
+                n_boundary=int((val < 0).sum()),
+                build_stats=list(manifest.get("covering_build") or []))
         return cls(census=census, covering=covering,
                    max_level=int(manifest["max_level"]),
                    gbits=int(manifest["gbits"]),

@@ -1,19 +1,14 @@
 """chip_smoke.py rehearsed without a chip: its phases at a tiny map on the
 CPU with the Pallas kernels in interpret mode (one device, and four
-virtual devices for the sharded paths), the parallel covering build it
-uses, and its refusal to report a result when no TPU is present.
+virtual devices for the sharded paths), and its refusal to report a
+result when no TPU is present.
 """
 import importlib.util
 import os
 import sys
 
-import numpy as np
-import pytest
-
 from subproc import REPO_ROOT, assert_subprocess_ok
 
-from repro.core import cells
-from repro.core.cells import build_cell_covering
 from repro.kernels import gather_pip
 
 
@@ -73,28 +68,3 @@ print("FOUR_OK")
     assert "assign_sharded: 1536 points, 1536 match ref" in out
     assert "assign_fast_distributed: 1536 points, 1536 match ref" in out
     assert "split 1 row per chip" in out
-
-
-@pytest.mark.parametrize("max_level", [5, 7])
-def test_parallel_covering_matches_sequential(synth_small, max_level,
-                                              monkeypatch):
-    one = build_cell_covering(synth_small.census, max_level=max_level)
-    # Take the parallel walk at this shallow level, on two processes.
-    monkeypatch.setattr(cells, "PARALLEL_MIN_LEVEL", max_level)
-    monkeypatch.setattr(cells, "_host_workers", lambda: 2)
-    splits = []
-    walk = cells._walk
-
-    def spy(ctx, stack, split_level=None):
-        splits.append(split_level)
-        return walk(ctx, stack, split_level)
-
-    monkeypatch.setattr(cells, "_walk", spy)
-    two = build_cell_covering(synth_small.census, max_level=max_level)
-    assert splits == [cells.PARALLEL_LEVEL]     # the rest ran in workers
-    for f in ("lo", "hi", "val", "level", "cand"):
-        np.testing.assert_array_equal(getattr(one, f), getattr(two, f),
-                                      err_msg=f)
-    assert (one.n_interior, one.n_boundary) == (two.n_interior,
-                                                two.n_boundary)
-
