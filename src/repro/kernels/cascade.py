@@ -31,9 +31,10 @@ in-kernel cell lookup), which a BlockSpec index map cannot express —
 index maps run before the body.  Hence the manual ``make_async_copy``
 double buffering; the pool stays in ``pl.ANY`` (HBM) and
 only the blocks a boundary point actually needs ever cross into VMEM.
-Unlike the BlockSpec pipeline in kernels/gather_pip.py there is no
-automatic revisit-skip across points, but interior points issue zero
-copies, so total edge traffic is bounded by boundary traffic alone.
+Unlike kernels/gather_pip.py, which shares one copy among a run of rows
+with the same block, there is no reuse across points, but interior
+points start zero copies, so total edge traffic is bounded by boundary
+traffic alone.
 
 Outputs (all [N] i32; ``ops.assign_cascade`` is the public dispatch):
 

@@ -94,9 +94,25 @@ def pip_candidates(points: jnp.ndarray, pids: jnp.ndarray, pool: EdgePool,
     (blocked-CSR; see kernels/gather_pip.py) — no gathered [N, E, 4]
     edge table is ever materialized in HBM.
     """
+    return _pip_candidates(points, pids, pool, backend, copies=False)
+
+
+def pip_candidates_copies(points: jnp.ndarray, pids: jnp.ndarray,
+                          pool: EdgePool, backend: str | None = None):
+    """``pip_candidates`` plus the kernel's count of HBM block copies:
+    returns ``(inside [N] bool, copies [] i32)``.  One copy serves each
+    run of rows (in the given order) with the same pool block, so sorted
+    ids need fewer.  Kernel backends only."""
+    return _pip_candidates(points, pids, pool, backend, copies=True)
+
+
+def _pip_candidates(points, pids, pool, backend, *, copies: bool):
     b = resolve_backend(backend)
+    if copies and b == "ref":
+        raise ValueError("the copy count needs a kernel backend")
     if pool.n_poly == 0:               # empty polygon table: nothing matches
-        return jnp.zeros(points.shape[0], jnp.bool_)
+        inside = jnp.zeros(points.shape[0], jnp.bool_)
+        return (inside, jnp.zeros((), jnp.int32)) if copies else inside
     valid = pids >= 0
     safe = jnp.clip(pids, 0, max(pool.n_poly - 1, 0))
     first = jnp.where(valid, pool.first[safe], 0).astype(jnp.int32)
@@ -105,24 +121,26 @@ def pip_candidates(points: jnp.ndarray, pids: jnp.ndarray, pool: EdgePool,
         cross = ref.crossings_candidates(points, first, nblk, pool.blocks,
                                          pool.max_blocks)
     else:
-        cross = _crossings_split(first, nblk, points.astype(jnp.float32),
-                                 pool.blocks, max_blocks=pool.max_blocks,
-                                 interpret=(b == "interpret"))
-    return (cross & 1).astype(jnp.bool_) & valid
+        cross, n_copies = _crossings_split(
+            first, nblk, points.astype(jnp.float32), pool.blocks,
+            max_blocks=pool.max_blocks, interpret=(b == "interpret"))
+    inside = (cross & 1).astype(jnp.bool_) & valid
+    return (inside, n_copies) if copies else inside
 
 
 @functools.partial(jax.jit, static_argnames=("max_blocks", "interpret"))
 def _crossings_split(first, nblk, points, blocks, *, max_blocks: int,
                      interpret: bool):
-    """The gather-PIP kernel over any number of rows: padded to its
-    sublane block (pad rows have no candidate) and, past
+    """The gather-PIP kernel over any number of rows: padded to whole
+    tiles (pad rows have no candidate) and, past
     ``gather_pip.MAX_ROWS``, split into equal calls whose
     scalar-prefetched tables fit SMEM.  The calls run as one ``lax.map``
-    so the kernel compiles once."""
+    so the kernel compiles once.  Returns (crossings [n] i32, HBM block
+    copies [] i32)."""
     n = points.shape[0]
     n_calls = -(-n // gather_pip_kernels.MAX_ROWS)
-    rows = gather_pip_kernels.ROWS
-    per = -(-n // (n_calls * rows)) * rows
+    tp = gather_pip_kernels.tile_rows(-(-n // n_calls))
+    per = -(-n // (n_calls * tp)) * tp
     pad = per * n_calls - n
     points = jnp.pad(points, ((0, pad), (0, 0)), constant_values=FAR)
     first = jnp.pad(first, (0, pad))
@@ -131,14 +149,16 @@ def _crossings_split(first, nblk, points, blocks, *, max_blocks: int,
     def call(args):
         f, c, p = args
         return gather_pip_kernels.crossings_candidates(
-            f, c, p, blocks, max_blocks=max_blocks, interpret=interpret)
+            f, c, p, blocks, max_blocks=max_blocks, interpret=interpret,
+            copies=True)
 
     if n_calls == 1:
-        return call((first, nblk, points))[:n]
-    cross = jax.lax.map(call, (first.reshape(n_calls, per),
-                               nblk.reshape(n_calls, per),
-                               points.reshape(n_calls, per, 2)))
-    return cross.reshape(-1)[:n]
+        cross, copies = call((first, nblk, points))
+        return cross[:n], jnp.sum(copies)
+    cross, copies = jax.lax.map(call, (first.reshape(n_calls, per),
+                                       nblk.reshape(n_calls, per),
+                                       points.reshape(n_calls, per, 2)))
+    return cross.reshape(-1)[:n], jnp.sum(copies)
 
 
 def assign_cascade(points: jnp.ndarray, quant: jnp.ndarray,

@@ -166,6 +166,115 @@ def test_pip_candidates_matches_legacy_gather_flow(ragged_world):
     np.testing.assert_array_equal(fused, legacy)
 
 
+def _pool_rows(pool, pids):
+    """The kernel's per-row (first, nblk) for candidate ids (ops.py)."""
+    valid = pids >= 0
+    safe = np.clip(pids, 0, None)
+    first = np.where(valid, np.asarray(pool.first)[safe], 0)
+    nblk = np.where(valid, np.asarray(pool.count)[safe], 0)
+    return first.astype(np.int32), nblk.astype(np.int32)
+
+
+def _tile_cases(n_poly):
+    """Candidate-id layouts for the tiled kernel, by name: row counts off
+    the tile size, long runs of one id, unsorted ids, whole tiles of -1,
+    a multi-block polygon and the pool's last block."""
+    rng = np.random.default_rng(21)
+    big = 3                                 # 300 edges: 3 blocks of 128
+    return {
+        "n1": rng.integers(-1, n_poly, 1),
+        "n8": rng.integers(-1, n_poly, 8),
+        "n300_unsorted": rng.integers(-1, n_poly, 300),
+        "n1000_unsorted": rng.integers(-1, n_poly, 1000),
+        "n1000_sorted": np.sort(rng.integers(0, n_poly, 1000)),
+        "long_run": np.concatenate([np.full(700, 2), np.full(300, 4)]),
+        "runs_across_tiles": np.repeat(rng.permutation(n_poly), 171)[:1000],
+        "empty_tiles_tail": np.concatenate(
+            [np.sort(rng.integers(0, n_poly, 200)), np.full(1400, -1)]),
+        "all_empty": np.full(1100, -1),
+        "multi_block": np.full(600, big),
+        "last_block": np.full(300, n_poly - 1),
+    }
+
+
+def _host_copies(first, nblk, max_blocks):
+    """HBM block copies the tiled kernel starts, from its schedule: per
+    call, tile and block step b, one per run of rows (in row order) with
+    the same valid block ``first + b``."""
+    from repro.kernels import gather_pip
+    n = len(first)
+    n_calls = -(-n // gather_pip.MAX_ROWS)
+    tp = gather_pip.tile_rows(-(-n // n_calls))
+    total = -(-n // (n_calls * tp)) * tp * n_calls
+    first = np.pad(first, (0, total - n))
+    nblk = np.pad(nblk, (0, total - n))
+    copies = 0
+    for lo in range(0, total, tp):
+        for b in range(max_blocks):
+            key = np.where(b < nblk[lo:lo + tp], first[lo:lo + tp] + b, -1)
+            new = key != np.concatenate([[-2], key[:-1]])
+            copies += int((new & (key >= 0)).sum())
+    return copies
+
+
+@pytest.mark.parametrize("case", list(_tile_cases(6)))
+def test_pip_candidates_tiles_bitexact_vs_ref(ragged_world, case):
+    """The tiled kernel's crossing counts equal the CSR ref oracle's bit
+    for bit on every id layout; sorting changes the copies, not the
+    answer."""
+    rings, dense, pool = ragged_world
+    pids = _tile_cases(len(rings))[case].astype(np.int32)
+    n = len(pids)
+    rng = np.random.default_rng(n)
+    pts = rng.uniform(-2, 4, (n, 2)).astype(np.float32)
+    first, nblk = _pool_rows(pool, pids)
+    args = (jnp.asarray(first), jnp.asarray(nblk), jnp.asarray(pts))
+    got, _ = ops._crossings_split(*args, pool.blocks,
+                                  max_blocks=pool.max_blocks,
+                                  interpret=True)
+    want = ref.crossings_candidates(args[2], args[0], args[1], pool.blocks,
+                                    pool.max_blocks)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    a = ops.pip_candidates(jnp.asarray(pts), jnp.asarray(pids), pool,
+                           backend="interpret")
+    b = ops.pip_candidates(jnp.asarray(pts), jnp.asarray(pids), pool,
+                           backend="ref")
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("case", ["n1000_unsorted", "n1000_sorted",
+                                  "runs_across_tiles", "empty_tiles_tail",
+                                  "multi_block", "all_empty"])
+def test_pip_candidates_copies_counts_runs(ragged_world, case):
+    """One HBM copy per run of equal valid blocks in row order (counted
+    per tile, as the kernel scans), none for an all -1 batch; the mask
+    is pip_candidates'."""
+    rings, dense, pool = ragged_world
+    pids = _tile_cases(len(rings))[case].astype(np.int32)
+    pts = np.random.default_rng(3).uniform(
+        -2, 4, (len(pids), 2)).astype(np.float32)
+    inside, copies = ops.pip_candidates_copies(
+        jnp.asarray(pts), jnp.asarray(pids), pool, backend="interpret")
+    first, nblk = _pool_rows(pool, pids)
+    want = _host_copies(first, nblk, pool.max_blocks)
+    assert int(copies) == want
+    if case == "all_empty":
+        assert want == 0
+    np.testing.assert_array_equal(
+        np.asarray(inside),
+        np.asarray(ops.pip_candidates(jnp.asarray(pts), jnp.asarray(pids),
+                                      pool, backend="ref")))
+
+
+def test_pip_candidates_copies_needs_a_kernel(ragged_world):
+    """The ref oracle makes no copies, so it has no count to give."""
+    rings, dense, pool = ragged_world
+    with pytest.raises(ValueError):
+        ops.pip_candidates_copies(jnp.zeros((8, 2), jnp.float32),
+                                  jnp.zeros((8,), jnp.int32), pool,
+                                  backend="ref")
+
+
 def test_edge_pool_empty_table():
     pool = ops.build_edge_pool(np.zeros((0, 4, 4), np.float32))
     out = np.asarray(ops.pip_candidates(

@@ -68,11 +68,15 @@ def _compile(fn, sharding, *shapes):
 F32, I32 = jnp.float32, jnp.int32
 
 
-def test_gather_pip_compiles_at_max_rows(one_chip):
-    r = gather_pip.MAX_ROWS
-    assert r == 65_536
+@pytest.mark.parametrize("r", [256, 1_792, 65_536])
+def test_gather_pip_compiles_at_max_rows(one_chip, r):
+    """The tiled gather-PIP at each tile size the cells use: a served
+    bucket's phase 1 (256 rows, one 256-row tile) and phase 2 (1,792
+    rows, padded to 512-row tiles), and a full call (MAX_ROWS)."""
+    assert gather_pip.MAX_ROWS == 65_536
     nb = N_BLOCKS + 1                      # block 0 reserved
-    fn = functools.partial(gather_pip.crossings_candidates, max_blocks=1)
+    fn = functools.partial(ops._crossings_split, max_blocks=1,
+                           interpret=False)
     _compile(fn, one_chip, ((r,), I32), ((r,), I32), ((r, 2), F32),
              ((nb, 4, gather_pip.DEF_BE), F32))
 
